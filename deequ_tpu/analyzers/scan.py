@@ -509,10 +509,10 @@ class _NumericScanAnalyzer(ScanShareableAnalyzer):
 
 
 def _pallas_moments(x, m):
-    """(count, sum, min, max) via the single-HBM-pass pallas fold when
+    """(count, sum) via the single-HBM-pass pallas fold when
     the knob/platform/shape allow, else None — the caller then runs its
     XLA fold. Blocked summation is a different float order, so whenever
-    this fires the plan signature carries the "pallas-folds" variant
+    this fires the plan signature carries the "pallas-kahan" variant
     (runtime.fold_variant()) and cached states never cross arithmetics."""
     from deequ_tpu.ops import pallas_kernels
 
@@ -537,7 +537,7 @@ class Mean(_NumericScanAnalyzer):
         x, m = self._masked(inputs, xp)
         folded = _pallas_moments(x, m)
         if folded is not None:
-            count, total, _mn, _mx = folded
+            count, total = folded
             return {"total": total, "count": count}
         return {"total": xp.sum(x * m), "count": xp.sum(m)}
 
@@ -577,7 +577,7 @@ class Sum(_NumericScanAnalyzer):
         x, m = self._masked(inputs, xp)
         folded = _pallas_moments(x, m)
         if folded is not None:
-            count, total, _mn, _mx = folded
+            count, total = folded
             return {"sum": total, "count": count}
         return {"sum": xp.sum(x * m), "count": xp.sum(m)}
 
@@ -605,6 +605,9 @@ class Minimum(_NumericScanAnalyzer):
 
     column: str
     where: Optional[str] = None
+    # the metric is one of the column's values, exact only in float64:
+    # folds on the host when the device wire is float32
+    value_exact = True
 
     @property
     def name(self) -> str:
@@ -615,10 +618,6 @@ class Minimum(_NumericScanAnalyzer):
             mom = self._moments(inputs)
             return {"min": mom["min"], "count": mom["count"]}
         x, m = self._masked(inputs, xp)
-        folded = _pallas_moments(x, m)
-        if folded is not None:
-            count, _total, mn, _mx = folded
-            return {"min": mn, "count": count}
         masked = xp.where(m > 0, x, xp.inf)
         return {"min": xp.min(masked), "count": xp.sum(m)}
 
@@ -646,6 +645,9 @@ class Maximum(_NumericScanAnalyzer):
 
     column: str
     where: Optional[str] = None
+    # the metric is one of the column's values, exact only in float64:
+    # folds on the host when the device wire is float32
+    value_exact = True
 
     @property
     def name(self) -> str:
@@ -656,10 +658,6 @@ class Maximum(_NumericScanAnalyzer):
             mom = self._moments(inputs)
             return {"max": mom["max"], "count": mom["count"]}
         x, m = self._masked(inputs, xp)
-        folded = _pallas_moments(x, m)
-        if folded is not None:
-            count, _total, _mn, mx = folded
-            return {"max": mx, "count": count}
         masked = xp.where(m > 0, x, -xp.inf)
         return {"max": xp.max(masked), "count": xp.sum(m)}
 
@@ -710,7 +708,7 @@ class StandardDeviation(_NumericScanAnalyzer):
         if folded is not None:
             from deequ_tpu.ops import pallas_kernels
 
-            n, total, _mn, _mx = folded
+            n, total = folded
             safe_n = xp.maximum(n, 1.0)
             avg = total / safe_n
             m2 = pallas_kernels.masked_centered_sumsq(x, m, avg)

@@ -87,6 +87,14 @@ def _hist16_available(n: int) -> bool:
 _BOOL_HLL = None
 
 
+def _bin16(keys32: np.ndarray) -> np.ndarray:
+    """Top 16 bits of float32 values' order-preserving sortable keys: the
+    host twin of `pallas_kernels.f32_sortable_bin16`."""
+    u = keys32.view(np.int32)
+    key = np.where(u < 0, ~u, u | np.int32(-(1 << 31)))
+    return (key >> 16) & 0xFFFF
+
+
 def _bool_hll_identities():
     """(idx, rank, packed) for the two canonical boolean identities
     (int64 0/1) — ONE definition shared by the per-row gather spec and
@@ -472,26 +480,77 @@ class _QuantileAnalyzerBase(ScanShareableAnalyzer):
             offset + stride * xp.arange(cap, dtype=xp.int32), len(vals) - 1
         )
         sample = sorted_vals[idx]
-        return {
+        out = {
             "sample": sample,
             "n": n[None] if hasattr(n, "shape") else xp.asarray([n]),
             "level": level[None].astype(xp.int32),
         }
+        if x.dtype == xp.float32:
+            # where each sample's run of equal float32 keys starts: the
+            # host swaps the sample for the column's own float64 value at
+            # the same rank (host_finish_batch)
+            out["lo"] = xp.searchsorted(sorted_vals, sample, side="left").astype(
+                xp.int32
+            )
+        return out
 
     def unshift_batch(self, out: Any, shifts) -> Any:
         s = shifts.get(f"num:{self.column}", 0.0)
-        if s == 0.0 or "sample" not in out:
+        if s == 0.0 or "sample" not in out or out.get("exact"):
             return out
         return {**out, "sample": np.asarray(out["sample"], dtype=np.float64) + s}
 
     def host_finish_batch(self, out: Any, host_inputs, shifts) -> Any:
-        """Finish the TPU hist16 radix-select: walk the 65536 counts to
-        the wanted decimation ranks, gather ONLY the owning bins from the
-        host-resident column, sort that sliver, read the samples off.
-        Exactly the decimated sample the device sort path would produce
-        (in the same float32 value space)."""
-        if "hist16" not in out:
-            return out
+        """Read a float32-wire batch's decimated sample off the column's
+        own float64 values, so every engine and batch size returns the
+        same, exact samples. Both device forms fix the ranks (the sort
+        path's offset + stride * i); float32 rounding is monotone, so the
+        float64 sort of the gathered rows keeps every rank. The result is
+        marked `exact` so no shift is undone on it.
+
+          * hist16 (TPU radix-select): walk the 65536 counts to the bins
+            that own a rank, gather ONLY those bins' rows, sort them;
+          * sorted sample + `lo` (the sort path): gather the rows whose
+            float32 key equals a sample's, sort them, and step `rank - lo`
+            into each key's run."""
+        if "hist16" in out:
+            return self._finish_hist16(out, host_inputs, shifts)
+        if "lo" in out:
+            return self._finish_sorted(out, host_inputs, shifts)
+        return out
+
+    def _wire_rows(self, host_inputs, shifts):
+        """(float64 values, live mask, float32 wire keys) of the batch's
+        host-resident column: the wire's value space, reproduced."""
+        x = np.asarray(host_inputs[f"num:{self.column}"], dtype=np.float64)
+        live = np.asarray(host_inputs[f"valid:{self.column}"], dtype=bool)
+        where = getattr(self, "where", None)
+        if where is not None:
+            live = live & np.asarray(host_inputs[where_key(where)], dtype=bool)
+        shift = shifts.get(f"num:{self.column}", 0.0)
+        xs32 = (x - shift).astype(np.float32) if shift != 0.0 else x.astype(
+            np.float32
+        )
+        return x, live, xs32
+
+    @staticmethod
+    def _ranks(n: int, level: int) -> np.ndarray:
+        """The decimation ranks at `level`: offset + stride * i."""
+        stride = 1 << level
+        offset = stride // 2
+        kept = max(0, -(-(n - offset) // stride))
+        return offset + stride * np.arange(kept, dtype=np.int64)
+
+    @staticmethod
+    def _exact(sample, n: int, level: int) -> dict:
+        return {
+            "sample": sample,
+            "n": np.asarray([n], dtype=np.float64),
+            "level": np.asarray([level], dtype=np.int32),
+            "exact": True,
+        }
+
+    def _finish_hist16(self, out: Any, host_inputs, shifts) -> Any:
         counts = np.asarray(out["hist16"], dtype=np.float64).reshape(65536)
         # bins 65409..65535: positive-NaN key region (impossible for
         # valid rows under the NaN==NULL contract) + the mask sentinel —
@@ -500,39 +559,18 @@ class _QuantileAnalyzerBase(ScanShareableAnalyzer):
         counts = counts.astype(np.int64)
         n = int(counts.sum())
         if n <= 0:
-            return {
-                "sample": np.zeros(0, dtype=np.float64),
-                "n": np.zeros(1, dtype=np.float64),
-                "level": np.zeros(1, dtype=np.int32),
-            }
-        cap = self._sample_size()
-        level = max(0, int(np.ceil(np.log2(max(n, 1) / cap))))
-        stride = 1 << level
-        offset = stride // 2
-        kept = max(0, -(-(n - offset) // stride))
-        ranks = offset + stride * np.arange(kept, dtype=np.int64)
+            return self._exact(np.zeros(0, dtype=np.float64), 0, 0)
+        level = max(0, int(np.ceil(np.log2(n / self._sample_size()))))
+        ranks = self._ranks(n, level)
 
         cum = np.cumsum(counts)
         bins_of_rank = np.searchsorted(cum, ranks, side="right")
         wanted = np.zeros(65536, dtype=bool)
         wanted[bins_of_rank] = True
 
-        # reproduce the wire's value space host-side: shifted float32
-        x = np.asarray(host_inputs[f"num:{self.column}"], dtype=np.float64)
-        valid = np.asarray(host_inputs[f"valid:{self.column}"], dtype=bool)
-        where = getattr(self, "where", None)
-        live = valid
-        if where is not None:
-            live = live & np.asarray(host_inputs[where_key(where)], dtype=bool)
-        shift = shifts.get(f"num:{self.column}", 0.0)
-        xs32 = (x - shift).astype(np.float32) if shift != 0.0 else x.astype(
-            np.float32
-        )
-        u = xs32.view(np.int32)
-        key = np.where(u < 0, ~u, u | np.int32(-(1 << 31)))
-        bin16 = (key >> 16) & 0xFFFF
-        sel = live & wanted[bin16]
-        gathered = np.sort(xs32[sel].astype(np.float64))
+        x, live, xs32 = self._wire_rows(host_inputs, shifts)
+        sel = live & wanted[_bin16(xs32)]
+        gathered = np.sort(x[sel])
 
         # rank within the gathered (wanted-bins-only) ordering: subtract
         # the mass of NON-wanted bins below each rank's bin
@@ -540,13 +578,31 @@ class _QuantileAnalyzerBase(ScanShareableAnalyzer):
         below = np.where(
             bins_of_rank > 0, unwanted_cum[bins_of_rank - 1], 0
         )
-        idx = ranks - below
-        sample = gathered[idx]
-        return {
-            "sample": sample,
-            "n": np.asarray([n], dtype=np.float64),
-            "level": np.asarray([level], dtype=np.int32),
-        }
+        return self._exact(gathered[ranks - below], n, level)
+
+    def _finish_sorted(self, out: Any, host_inputs, shifts) -> Any:
+        n = int(round(float(np.asarray(out["n"]).reshape(-1)[0])))
+        if n <= 0:
+            return self._exact(np.zeros(0, dtype=np.float64), 0, 0)
+        # the device's own level: the ranks its sample and `lo` refer to
+        level = int(np.asarray(out["level"]).reshape(-1)[0])
+        ranks = self._ranks(n, level)
+        kept = len(ranks)
+        # + 0.0 folds -0.0 into +0.0: the device sort ties them too
+        keys = np.asarray(out["sample"], dtype=np.float32)[:kept] + np.float32(0)
+        lo = np.asarray(out["lo"], dtype=np.int64)[:kept]
+
+        x, live, xs32 = self._wire_rows(host_inputs, shifts)
+        xs32 = xs32 + np.float32(0)
+        wanted = np.zeros(65536, dtype=bool)
+        wanted[_bin16(keys)] = True
+        sel = live & wanted[_bin16(xs32)]
+        sel[sel] = np.isin(xs32[sel], keys)
+        xv, kv = x[sel], xs32[sel]
+        order = np.argsort(xv, kind="stable")
+        gathered = xv[order]
+        start = np.searchsorted(kv[order], keys, side="left")
+        return self._exact(gathered[start + ranks - lo], n, level)
 
     def host_consume(self, state: Optional[State], out: Any) -> Optional[State]:
         n = int(round(float(np.asarray(out["n"]).reshape(-1)[0])))

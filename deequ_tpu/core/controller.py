@@ -16,7 +16,7 @@ All clock reads live here (core/), keeping the TIMING lint's ban on
 ad-hoc timing in ops/ and runners/ intact: those layers call
 `check()` / `beat()` and never read a clock themselves.
 
-The DQ4xx runtime taxonomy (plan-time lints own DQ1xx-DQ3xx):
+The DQ4xx runtime error codes (plan-time lints own DQ1xx-DQ3xx):
 
   * DQ401 — run cancelled by an explicit `cancel()`;
   * DQ402 — run deadline exceeded;

@@ -4,8 +4,10 @@ sinks and active tracers.
 `ops/runtime.py`'s `monitored()` / `record_pass()` / `record_launch()`
 delegate here (source-compatible migration, ISSUE 3 tentpole). A sink
 is any object with `device_passes` / `device_launches` / `group_passes`
-ints and a `pass_labels` list — `runtime.ExecutionStats` in practice,
-duck-typed so this module never imports the ops layer.
+ints, a `pass_labels` list, a `kernel_traces` dict and the placement
+counts `placed_rows` / `device_rows` —
+`runtime.ExecutionStats` in practice, duck-typed so this module never
+imports the ops layer.
 
 The sink stack is thread-local (concurrent monitored scans on separate
 threads never cross-contaminate), and every record also feeds the
@@ -61,6 +63,30 @@ def record_launch() -> None:
     tracer = spans.current_tracer()
     if tracer is not None:
         tracer.count("device_launches", 1)
+
+
+def record_kernel(name: str) -> None:
+    """One Pallas kernel placed into a program being traced. Kernels run
+    inside jitted programs, so this counts programs built around the
+    kernel, not executions: a nonzero count proves the kernel (and not
+    its XLA fallback) is in the dispatched program."""
+    for sink in _sinks():
+        sink.kernel_traces[name] = sink.kernel_traces.get(name, 0) + 1
+    tracer = spans.current_tracer()
+    if tracer is not None:
+        tracer.count(f"kernel.{name}", 1)
+
+
+def record_placement(rows: int, device_rows) -> None:
+    """Mesh-sharded inputs placed: `rows` in all, `device_rows[device id]`
+    held by each device."""
+    for sink in _sinks():
+        sink.placed_rows += rows
+        for dev, n in device_rows.items():
+            sink.device_rows[dev] = sink.device_rows.get(dev, 0) + n
+    tracer = spans.current_tracer()
+    if tracer is not None:
+        tracer.count("placed_rows", rows)
 
 
 def record_group_pass(label: str) -> None:

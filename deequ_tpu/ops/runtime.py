@@ -10,9 +10,10 @@ property (SURVEY.md §6 efficiency invariants).
 from __future__ import annotations
 
 import contextlib
+import os
 import time
 from dataclasses import dataclass, field
-from typing import Iterator, List, Optional
+from typing import Dict, Iterator, List, Optional
 
 import jax
 import jax.numpy as jnp
@@ -83,6 +84,32 @@ def narrow_int_wire(arr: np.ndarray, key: str, sticky: dict) -> np.ndarray:
 
 
 # ---------------------------------------------------------------------------
+# Persistent compilation cache
+# ---------------------------------------------------------------------------
+
+# <checkout>/.jax_cache: fixed, because the directory is part of what a
+# later process looks entries up by
+_CHECKOUT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".jax_cache",
+)
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for this process and
+    return its directory. Entry points (chip_smoke.py, bench.py, tools/)
+    call this before their first compile; the library never does on
+    import. Where `JAX_COMPILATION_CACHE_DIR` is set, JAX already reads
+    it and nothing is changed; otherwise the cache lives at
+    `<checkout>/.jax_cache`."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", _CHECKOUT_CACHE_DIR)
+    return _CHECKOUT_CACHE_DIR
+
+
+# ---------------------------------------------------------------------------
 # Placement: where a reduction earns its bytes
 # ---------------------------------------------------------------------------
 
@@ -98,10 +125,10 @@ PLACEMENT_BANDWIDTH_FLOOR = 100e6  # bytes/s: below, nothing earns the wire
 
 def measure_device_bandwidth(nbytes: int = 4 << 20, iters: int = 3) -> float:
     """Effective H2D+D2H bandwidth probe (synchronized via a value fetch —
-    async dispatch makes un-fetched timings meaningless on tunneled
-    devices). Best-of-`iters` with a measured empty-dispatch baseline
-    subtracted, so per-dispatch latency doesn't misclassify a fast
-    (PCIe-class) link as slow on a one-shot noisy sample."""
+    async dispatch makes un-fetched timings meaningless). Best-of-`iters`
+    with a measured empty-dispatch baseline subtracted, so per-dispatch
+    latency doesn't misclassify a fast (PCIe-class) link as slow on a
+    one-shot noisy sample."""
     data = np.zeros(nbytes // 4, dtype=np.float32)
     tiny = np.zeros(1, dtype=np.float32)
     total = jax.jit(jnp.sum)
@@ -121,6 +148,15 @@ def measure_device_bandwidth(nbytes: int = 4 << 20, iters: int = 3) -> float:
     return nbytes / max(best - dispatch, 1e-9)
 
 
+def placement_for_bandwidth(bandwidth: float) -> str:
+    """The placement a measured host-to-device bandwidth (bytes/s) earns."""
+    if bandwidth >= PLACEMENT_DEVICE_ALL_BANDWIDTH:
+        return "device"
+    if bandwidth >= PLACEMENT_BANDWIDTH_FLOOR:
+        return "host-discrete"
+    return "host-all"
+
+
 def placement_mode() -> str:
     """Where reductions run, by measured link economics:
 
@@ -130,22 +166,21 @@ def placement_mode() -> str:
       'host-discrete' — mask/code-only reductions fold on the host;
                         value-dense work (moments, sorts) still earns its
                         4 B/row on a mid-speed link
-      'host-all'      — the link is slower than the host can simply
-                        REDUCE (e.g. a ~10 MB/s tunnel): every analyzer
-                        folds on the host through the same xp-generic
-                        reduction code; the device program is skipped
+      'host-all'      — the host link is slower than the host can simply
+                        REDUCE: every analyzer folds on the host through
+                        the same xp-generic reduction code; the device
+                        program is skipped
 
     The scheduler analogue of Spark's map-side combine decision, decided
     by a synchronized bandwidth probe whose measurement is cached on disk
-    per (host, platform, device kind) with a TTL (PLACEMENT_CACHE_TTL_S) — on
-    slow tunnels the probe costs seconds of startup per process, so only
-    the first process in a week pays it. Override with
+    per (host, platform, device kind) with a TTL (PLACEMENT_CACHE_TTL_S),
+    so only the first process in a week pays the probe's compiles. A
+    probe that fails raises: a failure is never read as a slow link that
+    sends the work to the host. Override with
     DEEQU_TPU_PLACEMENT=device|host-discrete|host|auto ('host' =
     host-all); delete <cache dir>/placement.json to force a re-probe.
     """
     global _PLACEMENT_CACHE
-    import os
-
     env = os.environ.get("DEEQU_TPU_PLACEMENT", "auto")
     if env == "device":
         return "device"
@@ -156,19 +191,10 @@ def placement_mode() -> str:
     if _PLACEMENT_CACHE is None:
         bandwidth = _load_bandwidth_from_disk()
         if bandwidth is None:
-            try:
-                bandwidth = measure_device_bandwidth()
-            except Exception:  # noqa: BLE001 - no device at all -> host
-                _PLACEMENT_CACHE = "host-all"
-                return _PLACEMENT_CACHE
+            bandwidth = measure_device_bandwidth()
             _save_bandwidth_to_disk(bandwidth)
         # classify at use time, so cached probes survive threshold tuning
-        if bandwidth >= PLACEMENT_DEVICE_ALL_BANDWIDTH:
-            _PLACEMENT_CACHE = "device"
-        elif bandwidth >= PLACEMENT_BANDWIDTH_FLOOR:
-            _PLACEMENT_CACHE = "host-discrete"
-        else:
-            _PLACEMENT_CACHE = "host-all"
+        _PLACEMENT_CACHE = placement_for_bandwidth(bandwidth)
     return _PLACEMENT_CACHE
 
 
@@ -295,7 +321,7 @@ def share_group_max() -> int:
 
 
 def pallas_folds_enabled() -> bool:
-    """Whether the numeric moments/min-max state folds may run as
+    """Whether the numeric moments state folds may run as
     Pallas kernels (ops/pallas_kernels.py) on platforms that compile
     them. `DEEQU_TPU_PALLAS_FOLDS=0` (or `off`) is the kill switch.
     Call sites additionally require `pallas_kernels.usable()` (a TPU
@@ -311,16 +337,28 @@ def pallas_folds_enabled() -> bool:
 
 
 def fold_variant() -> str:
-    """The fold-arithmetic variant tag the plan signature hashes:
-    "pallas-folds" when the Pallas moments folds are enabled AND the
-    platform actually compiles them, else "" (the default arithmetic —
-    signatures unchanged). On CPU this is always "" — interpret-mode
-    kernel runs live only in tests, never in the product fold."""
-    if not pallas_folds_enabled():
-        return ""
-    from deequ_tpu.ops import pallas_kernels
+    """The fold-arithmetic variant tag the plan signature hashes, "+"-
+    joined from:
 
-    return "pallas-folds" if pallas_kernels.usable() else ""
+      * "f32-exact" on the float32 wire: Minimum/Maximum fold on the host
+        (`value_exact`) and quantile samples are read off the column's
+        float64 values — states from before carried float32-rounded
+        values;
+      * "pallas-kahan" when the Pallas moments folds are enabled AND the
+        platform actually compiles them (compensated blocked sums).
+
+    "" on the float64 wire without Pallas (the default arithmetic —
+    signatures unchanged): the CPU tests, where interpret-mode kernel
+    runs live only in tests, never in the product fold."""
+    tags = []
+    if compute_dtype() == jnp.float32:
+        tags.append("f32-exact")
+    if pallas_folds_enabled():
+        from deequ_tpu.ops import pallas_kernels
+
+        if pallas_kernels.usable():
+            tags.append("pallas-kahan")
+    return "+".join(tags)
 
 
 def fold_signature_variant() -> str:
@@ -684,8 +722,8 @@ def heartbeat_s() -> float:
 def _platform_key() -> Optional[str]:
     """Identity of the attached LINK — the cache key. Bandwidth is a
     property of how THIS HOST reaches the device, not of the device kind
-    alone: the same device kind reached locally vs over a tunnel has
-    wildly different bandwidth, so the host name is part of the key."""
+    alone: one device kind can sit behind host links of very different
+    bandwidth, so the host name is part of the key."""
     import socket
 
     try:
@@ -708,8 +746,8 @@ def _placement_cache_path() -> Optional[str]:
 
 
 def _load_bandwidth_from_disk() -> Optional[float]:
-    """The probe costs seconds of real time on slow tunnels (two device
-    compiles + synchronized fetches), so the MEASURED BANDWIDTH is
+    """The probe costs two device compiles plus synchronized fetches at
+    every process start, so the MEASURED BANDWIDTH is
     cached per (host, platform, device kind) with a TTL. Delete the file
     (or set DEEQU_TPU_PLACEMENT) to force a re-probe."""
     import json
@@ -782,6 +820,11 @@ class ExecutionStats:
     device_launches: int = 0  # one per compiled-program invocation (per batch)
     group_passes: int = 0  # one per group-by frequency computation
     pass_labels: List[str] = field(default_factory=list)
+    # Pallas kernel name -> programs traced with that kernel in them
+    kernel_traces: Dict[str, int] = field(default_factory=dict)
+    # mesh-sharded inputs: rows placed (global) and rows each device holds
+    placed_rows: int = 0
+    device_rows: Dict[str, int] = field(default_factory=dict)
 
     @property
     def jobs(self) -> int:
@@ -807,6 +850,23 @@ def record_pass(label: str) -> None:
 
 def record_launch() -> None:
     _counters.record_launch()
+
+
+def record_placement(arrays) -> None:
+    """Count the rows of mesh-sharded input arrays just placed, and the
+    rows each device holds of them (from each array's shard index, so
+    nothing is fetched): a sharded input spreads n rows as n/d per
+    device, a replicated or single-device one does not."""
+    total = 0
+    per_device: Dict[str, int] = {}
+    for arr in arrays:
+        n = int(arr.shape[0])
+        total += n
+        for shard in arr.addressable_shards:
+            rows = len(range(*shard.index[0].indices(n)))
+            key = str(shard.device.id)
+            per_device[key] = per_device.get(key, 0) + rows
+    _counters.record_placement(total, per_device)
 
 
 def record_group_pass(label: str) -> None:

@@ -51,29 +51,6 @@ _DIST_CACHE: Dict[Any, Any] = {}
 _DIST_CACHE_LOCK = threading.Lock()
 
 
-def _shard_map(f, *, mesh, in_specs, out_specs, check_vma=False):
-    """`jax.shard_map` moved to top level around jax 0.6; on earlier
-    versions (e.g. 0.4.x) it lives in jax.experimental.shard_map and the
-    `check_vma` kwarg is spelled `check_rep`."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f,
-            mesh=mesh,
-            in_specs=in_specs,
-            out_specs=out_specs,
-            check_vma=check_vma,
-        )
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(
-        f,
-        mesh=mesh,
-        in_specs=in_specs,
-        out_specs=out_specs,
-        check_rep=check_vma,
-    )
-
-
 def data_mesh(devices: Optional[Sequence] = None, axis_name: str = DATA_AXIS) -> Mesh:
     """1-D data-parallel mesh over all (or given) devices."""
     devices = list(devices) if devices is not None else jax.devices()
@@ -132,7 +109,7 @@ def _get_distributed_fn(analyzers, mesh: Mesh, axis_name: str, assisted=()):
         assisted_out = tuple(a.device_batch(inputs, jnp) for a in assisted)
         return tuple(merged), assisted_out
 
-    sharded = _shard_map(
+    sharded = jax.shard_map(
         per_device,
         mesh=mesh,
         in_specs=(P(axis_name),),
@@ -144,6 +121,14 @@ def _get_distributed_fn(analyzers, mesh: Mesh, axis_name: str, assisted=()):
     with _DIST_CACHE_LOCK:
         fn = _DIST_CACHE.setdefault(key, fn)
     return fn
+
+
+def _shard_rows(inputs: Dict[str, Any], n_devices: int) -> int:
+    """Rows of the batch each device holds: every device key is padded to
+    one length and split evenly along the mesh axis."""
+    for arr in inputs.values():
+        return int(arr.shape[0]) // n_devices
+    return 0
 
 
 class DistributedScanPass:
@@ -303,7 +288,12 @@ class DistributedScanPass:
                                         )
                                     )
                                 runtime.record_launch()
-                                fold.submit(fn(inputs))
+                                runtime.record_placement(inputs.values())
+                                fold.submit(
+                                    fn(inputs),
+                                    host_ctx=built,
+                                    shard_rows=_shard_rows(inputs, n_devices),
+                                )
                         except Exception as e:  # noqa: BLE001
                             device_error = e
                     with observe.span(
@@ -441,7 +431,14 @@ class DistributedScanPass:
                             elif inputs is not None:
                                 try:
                                     runtime.record_launch()
-                                    fold.submit(fn(inputs))
+                                    runtime.record_placement(inputs.values())
+                                    fold.submit(
+                                        fn(inputs),
+                                        host_ctx=built,
+                                        shard_rows=_shard_rows(
+                                            inputs, n_devices
+                                        ),
+                                    )
                                 except Exception as e:  # noqa: BLE001
                                     device_error = e
                             if device_error is not None:
@@ -495,7 +492,7 @@ def sharded_bincount(
             return jax.lax.psum(counts, axis_name)
 
         fn = jax.jit(
-            _shard_map(
+            jax.shard_map(
                 per_device,
                 mesh=mesh,
                 in_specs=(P(axis_name),),
@@ -513,8 +510,9 @@ def sharded_bincount(
         devices=int(n_devices),
     ):
         runtime.record_launch()
-        sharding = NamedSharding(mesh, P(axis_name))
-        counts = np.asarray(fn(jax.device_put(full, sharding)))
+        placed = jax.device_put(full, NamedSharding(mesh, P(axis_name)))
+        runtime.record_placement([placed])
+        counts = np.asarray(fn(placed))
     return counts[:nbins].astype(np.int64)
 
 

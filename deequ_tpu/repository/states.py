@@ -99,9 +99,10 @@ def plan_signature(
     EXCLUDED: pipeline/pushdown/decode/wire knobs — the differential
     suites prove those bit-identical, so toggling them must not evict
     the cache. `variant` names a fold-arithmetic variant that is NOT
-    bit-identical to the default (today: "pallas-folds", the on-TPU
-    blocked Pallas moments fold) — the empty default leaves signatures
-    unchanged."""
+    bit-identical to the default (today: "f32-exact" for the float32
+    wire's exact-value folds and "pallas-kahan" for the on-TPU blocked
+    Pallas moments fold, see `runtime.fold_variant`) — the empty default
+    leaves signatures unchanged."""
     h = _DIGEST()
     h.update(STATE_MAGIC)
     h.update(struct.pack(">I", STATE_FORMAT_VERSION))

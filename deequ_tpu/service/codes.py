@@ -1,6 +1,6 @@
 """The service-level DQ4xx codes (ISSUE 14).
 
-The runtime taxonomy splits in two: `core/controller.py` owns the
+The runtime error codes split in two: `core/controller.py` owns the
 codes a RUN ends with (DQ401-DQ407 — cancelled, deadline, stalled,
 preempted, quota-at-boundary, drain), while this module owns the codes
 a SUBMISSION is turned away with before or instead of running:

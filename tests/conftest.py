@@ -29,12 +29,7 @@ os.environ.setdefault("JAX_ENABLE_X64", "true")
 import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
-try:
-    jax.config.update("jax_num_cpu_devices", 8)
-except AttributeError:
-    # older jax (< 0.4.34 area) has no such option; the
-    # xla_force_host_platform_device_count flag above does the same job
-    pass
+jax.config.update("jax_num_cpu_devices", 8)
 jax.config.update(
     "jax_enable_x64", os.environ["JAX_ENABLE_X64"].lower() in ("1", "true")
 )

@@ -372,7 +372,9 @@ class TestObservability:
             ).run()
         rec = engine_metric_record(handle.trace)
         assert rec["engine.decode_fastpath_ratio"] == 0.5
-        assert rec["engine.decode_workers"] == 1.0
+        from deequ_tpu.ops import runtime
+
+        assert rec["engine.decode_workers"] == float(runtime.decode_workers())
 
         import importlib.util
         import os

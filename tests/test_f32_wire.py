@@ -235,3 +235,77 @@ class TestIllConditionedF32:
         res = FusedScanPass([StandardDeviation("x")]).run(t)
         sd = res[0].state_or_raise().metric_value()
         assert sd == pytest.approx(float(np.nanstd(x)), rel=1e-3)
+
+
+class TestExactValuesOnF32Wire:
+    """Metrics that ARE one of the column's values (min, max, the
+    quantile samples) come back as the column's own float64 values on
+    the float32 wire, as on the float64 one: Minimum/Maximum fold on the
+    host (`value_exact`), and quantile samples are read off the host
+    column, on one device and per shard across a mesh."""
+
+    @staticmethod
+    def _x(n=20_000):
+        rng = np.random.default_rng(5)
+        x = 1.0e7 + rng.lognormal(0.0, 1.0, n) * np.where(
+            rng.random(n) < 0.3, -1.0, 1.0
+        )
+        x[rng.random(n) < 0.05] = np.nan
+        return x
+
+    def test_min_max_plan_on_host(self, f32_engine):
+        from deequ_tpu.ops.fused import plan_scan_members
+
+        plan = plan_scan_members(
+            [Mean("x"), Minimum("x"), Maximum("x")], mode="device"
+        )
+        assert plan.merge_idx == [0]
+        assert plan.host_idx == [1, 2]
+        # states folded this way never mix with older float32-wire ones
+        assert "f32-exact" in runtime.fold_variant().split("+")
+
+    def test_min_max_exact(self, f32_engine):
+        x = self._x()
+        res = FusedScanPass([Minimum("x"), Maximum("x")], batch_size=4096).run(
+            Table.from_numpy({"x": x})
+        )
+        assert res[0].state_or_raise().metric_value() == np.nanmin(x)
+        assert res[1].state_or_raise().metric_value() == np.nanmax(x)
+
+    @pytest.mark.parametrize("engine", ["single", "mesh"])
+    def test_quantiles_equal_the_float64_engine(self, monkeypatch, engine):
+        """Same ranks, same float64 samples: the float32 wire's quantiles
+        equal the float64 engine's bit for bit, over several batches (and
+        shards, whose host rows must line up with the device's)."""
+        import jax.numpy as jnp
+
+        from deequ_tpu.parallel import DistributedScanPass, data_mesh
+
+        monkeypatch.setenv("DEEQU_TPU_PLACEMENT", "device")
+        x = self._x()
+        table = Table.from_numpy({"x": x})
+        analyzers = [ApproxQuantile("x", q) for q in (0.1, 0.5, 0.97)]
+
+        def run():
+            if engine == "single":
+                results = FusedScanPass(analyzers, batch_size=4096).run(table)
+            else:
+                results = DistributedScanPass(
+                    analyzers, mesh=data_mesh(), batch_size_per_device=512
+                ).run(table)
+            return [
+                r.analyzer.compute_metric_from(r.state_or_raise()).value.get()
+                for r in results
+            ]
+
+        want = run()
+        monkeypatch.setattr(runtime, "compute_dtype", lambda: jnp.float32)
+        with runtime.monitored() as stats:
+            got = run()
+        assert got == want
+        assert set(got) <= set(x[~np.isnan(x)])
+        if engine == "mesh":
+            # every device held its even share of the sharded inputs
+            shares = set(stats.device_rows.values())
+            assert len(stats.device_rows) == 8 and len(shares) == 1
+            assert shares.pop() * 8 == stats.placed_rows > 0

@@ -123,17 +123,17 @@ class TestHist16RadixSelect:
         assert (np.diff(ref_bins[live][order]) >= 0).all()
 
     def test_quantile_path_equals_sort_path(self, monkeypatch):
-        """End-to-end through the f32 device engine: the hist16 path's
-        samples equal the sort path's (identical decimation ranks in the
-        identical value space), so the resulting quantiles match
-        exactly. Engagement is asserted, not assumed."""
+        """End-to-end through the f32 device engine: both paths pick the
+        same decimation ranks and read the samples off the column's own
+        float64 values, so the resulting quantiles match exactly, equal
+        the float64 engine's and are values of the column. Engagement is
+        asserted, not assumed."""
         import deequ_tpu.analyzers.sketch as sketch_mod
         from deequ_tpu.analyzers import ApproxQuantile
         from deequ_tpu.data.table import Table
         from deequ_tpu.ops import runtime
         from deequ_tpu.ops.fused import FusedScanPass
 
-        monkeypatch.setattr(runtime, "compute_dtype", lambda: jnp.float32)
         monkeypatch.setenv("DEEQU_TPU_PLACEMENT", "device")
 
         rng = np.random.default_rng(8)
@@ -149,9 +149,10 @@ class TestHist16RadixSelect:
             calls["hist16"] += 1
             return real_hist16(bins, interpret=True)
 
-        def run(use_hist):
+        def run(use_hist, dtype=jnp.float32):
             # KLL seeds are content-derived (sketch._batch_seed): equal
             # samples give equal sketches with no counter pinning
+            monkeypatch.setattr(runtime, "compute_dtype", lambda: dtype)
             if use_hist:
                 monkeypatch.setattr(
                     sketch_mod, "_hist16_available", lambda n: True
@@ -170,16 +171,18 @@ class TestHist16RadixSelect:
         assert calls["hist16"] >= 1  # the kernel actually ran
         via_sort = run(False)
         assert via_hist == via_sort, (via_hist, via_sort)
+        via_sort_f64 = run(False, jnp.float64)
+        assert via_hist == via_sort_f64, (via_hist, via_sort_f64)
+        assert via_hist in set(x[~np.isnan(x)])
 
 
 class TestMaskedMomentFolds:
-    """ISSUE 15 satellite: the numeric analyzers' count/sum/min/max (+
-    stddev m2) folds as single-HBM-pass pallas kernels, pinned in
-    interpret mode against an identically-blocked XLA reference —
-    BITWISE for every stat (blocked summation is its own arithmetic;
-    that is exactly what the "pallas-folds" plan-signature variant
-    isolates), and exactly for the order-insensitive stats vs the naive
-    fold."""
+    """ISSUE 15 satellite: the numeric analyzers' count/sum (+ stddev
+    m2) folds as single-HBM-pass pallas kernels, pinned in interpret
+    mode against an identically-blocked XLA reference — BITWISE for
+    every stat (blocked summation is its own arithmetic; that is exactly
+    what the "pallas-kahan" plan-signature variant isolates), and
+    exactly for the count vs the naive fold."""
 
     @staticmethod
     def _data(n, seed, all_masked=False):
@@ -194,22 +197,21 @@ class TestMaskedMomentFolds:
     @staticmethod
     def _blocked_reference(x, m):
         """The kernel's exact accumulation order in plain jnp ops:
-        (8, 128) lane accumulators over the sequential grid, then the
-        same tiny lane-reduce epilog."""
+        (8, 128) Kahan-compensated lane accumulators over the sequential
+        grid, then the same tiny lane-reduce epilog."""
         x3 = x.reshape(-1, 8, 128)
         m3 = m.reshape(-1, 8, 128)
         cnt = jnp.zeros((8, 128), jnp.float32)
         tot = jnp.zeros((8, 128), jnp.float32)
-        mn = jnp.full((8, 128), jnp.inf, jnp.float32)
-        mx = jnp.full((8, 128), -jnp.inf, jnp.float32)
+        comp = jnp.zeros((8, 128), jnp.float32)
         for blk in range(x3.shape[0]):
             xb, mb = x3[blk], m3[blk]
-            live = mb > 0
             cnt = cnt + mb
-            tot = tot + xb * mb
-            mn = jnp.minimum(mn, jnp.where(live, xb, jnp.inf))
-            mx = jnp.maximum(mx, jnp.where(live, xb, -jnp.inf))
-        return jnp.sum(cnt), jnp.sum(tot), jnp.min(mn), jnp.max(mx)
+            y = xb * mb - comp
+            t = tot + y
+            comp = (t - tot) - y
+            tot = t
+        return jnp.sum(cnt), jnp.sum(tot - comp)
 
     @pytest.mark.parametrize("n", [1024, 4096, 1 << 14])
     def test_bitwise_vs_blocked_xla_reference(self, n):
@@ -222,28 +224,44 @@ class TestMaskedMomentFolds:
 
     def test_order_insensitive_stats_match_naive_fold_exactly(self):
         x, m = self._data(4096, seed=3)
-        cnt, total, mn, mx = [
+        cnt, total = [
             np.asarray(v)
             for v in pallas_kernels.masked_moments(x, m, interpret=True)
         ]
         xn, mn_np = np.asarray(x), np.asarray(m)
-        live = xn[mn_np > 0]
         assert cnt == mn_np.sum()
-        assert mn == live.min()
-        assert mx == live.max()
         # sums reassociate: allclose, not bitwise, vs the naive fold
         np.testing.assert_allclose(
             total, (xn * mn_np).sum(dtype=np.float32), rtol=1e-5
         )
 
+    def test_compensated_sums_hold_1e_6(self):
+        """TPC-H l_orderkey over 1024 blocks: a plain f32 lane sum of
+        this column drifts to ~4e-6 relative; the compensated one must
+        hold the 1e-6 parity target."""
+        from deequ_tpu.testing.tpch import lineitem_columns
+
+        key = np.asarray(lineitem_columns(1 << 20)["l_orderkey"], np.float64)
+        x = jnp.asarray(key, dtype=jnp.float32)
+        m = jnp.ones(x.shape[0], dtype=jnp.float32)
+        _cnt, total = pallas_kernels.masked_moments(
+            x, m, interpret=True
+        )
+        assert abs(float(total) - key.sum()) / key.sum() < 1e-6
+        avg = key.mean()
+        m2 = pallas_kernels.masked_centered_sumsq(
+            x, m, jnp.float32(avg), interpret=True
+        )
+        want = ((key - np.float32(avg)) ** 2).sum()
+        assert abs(float(m2) - want) / want < 1e-6
+
     def test_all_masked_yields_identities(self):
         x, m = self._data(1024, seed=5, all_masked=True)
-        cnt, total, mn, mx = [
+        cnt, total = [
             np.asarray(v)
             for v in pallas_kernels.masked_moments(x, m, interpret=True)
         ]
         assert cnt == 0.0 and total == 0.0
-        assert mn == np.inf and mx == -np.inf
 
     def test_centered_sumsq_matches_stddev_fold(self):
         x, m = self._data(2048, seed=11)
@@ -291,7 +309,7 @@ class TestMaskedMomentFolds:
                                  batch_rows=None, variant="")
         pallas = plan_signature([Mean("x")], placement="device",
                                 compute_dtype="float32", batch_size=None,
-                                batch_rows=None, variant="pallas-folds")
+                                batch_rows=None, variant="pallas-kahan")
         # empty variant leaves existing signatures unchanged; the pallas
         # arithmetic gets its own cache namespace
         assert base == default

@@ -69,7 +69,10 @@ WORKER = textwrap.dedent(
         Completeness, Maximum, Mean, Minimum, StandardDeviation, Sum,
     )
     from deequ_tpu.data.source import PartitionedParquetSource
+    from deequ_tpu.ops.runtime import use_compile_cache
     from deequ_tpu.parallel import run_sharded_analysis
+
+    use_compile_cache()
 
     _round = [0]
     _gather_entry = [0.0]

@@ -121,9 +121,11 @@ def run_interactive_round(svc, table, tag):
 def main() -> int:
     from deequ_tpu.data.table import Table
     from deequ_tpu.lint.explain import explain_plan
+    from deequ_tpu.ops.runtime import use_compile_cache
     from deequ_tpu.repository.states import FileSystemStateRepository
     from deequ_tpu.service import DQService
 
+    use_compile_cache()
     total_rows = int(os.environ.get("BENCH_SERVICE_ROWS", "2000000"))
     rows_per_part = max(1, total_rows // N_PARTITIONS)
 

@@ -141,8 +141,10 @@ def submit_round(svc, open_table, checks, blocker_table):
 def main() -> int:
     from deequ_tpu import VerificationSuite
     from deequ_tpu.data.table import Table
+    from deequ_tpu.ops.runtime import use_compile_cache
     from deequ_tpu.service import DQService
 
+    use_compile_cache()
     total_rows = int(os.environ.get("BENCH_SHARING_ROWS", "8000000"))
     rows_per_part = max(1, total_rows // N_PARTITIONS)
     checks = tenant_checks()
