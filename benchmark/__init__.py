@@ -1,0 +1,1 @@
+"""The driver's benchmark: `python3 benchmark/run.py --workload <cell> ...`."""

@@ -1,0 +1,10 @@
+"""95th percentile over every verdict of the window (no per-chunk
+medians), in milliseconds."""
+
+import numpy as np
+
+
+def read(run):
+    if not run.calls:
+        return None
+    return float(np.percentile([(c.t1 - c.t0) * 1e3 for c in run.calls], 95))

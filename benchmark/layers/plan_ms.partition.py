@@ -1,0 +1,9 @@
+"""Self time of the program's `plan` spans (runners/, lint/,
+ops/fused.py:plan_scan_members) per verdict, ms.
+"""
+
+from benchmark.harness.spans import ms_per_call, of_category, self_seconds
+
+
+def read(run):
+    return ms_per_call(run, self_seconds(run.spans, of_category("plan")))
