@@ -323,6 +323,10 @@ class GroupCountAccumulator:
         self._buffer = None
         self._writer: Optional[_SpillWriter] = None
 
+    @property
+    def spilled(self) -> bool:
+        return self._writer is not None
+
     def add(self, partial) -> None:
         if self._writer is not None:
             self._writer.append(partial)  # num_rows accumulates in append

@@ -19,6 +19,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from deequ_tpu import observe
 from deequ_tpu.analyzers.base import Preconditions, entity_from
 from deequ_tpu.analyzers.grouping import GroupingAnalyzer
 from deequ_tpu.analyzers.states import State
@@ -224,7 +225,6 @@ def compute_frequencies(
     state merge — bounded host memory at O(#groups), never O(#rows).
     With a mesh, the count aggregation runs row-sharded on the devices
     (psum merge); the host keeps dict-encode and key bookkeeping."""
-    from deequ_tpu import observe
     from deequ_tpu.ops import runtime
 
     with observe.span(
@@ -250,8 +250,15 @@ def _compute_frequencies(
 
         acc = GroupCountAccumulator(grouping_columns)
         for batch in data.batches(getattr(data, "batch_rows", 1 << 22)):
-            acc.add(_frequencies_of_batch(batch, grouping_columns, mesh))
-        state = acc.finalize()
+            partial = _frequencies_of_batch(batch, grouping_columns, mesh)
+            with observe.span("group_merge", cat="group") as sp:
+                acc.add(partial)
+                if sp:
+                    sp.set(groups=int(partial.num_groups), spilled=acc.spilled)
+        with observe.span("group_merge", cat="group") as sp:
+            state = acc.finalize()
+            if sp:
+                sp.set(groups=int(state.num_groups), spilled=acc.spilled)
         if num_rows is not None:
             state.num_rows = num_rows
         return state
@@ -270,18 +277,33 @@ _MAX_DEVICE_BINS = 1 << 20
 def _frequencies_of_batch(
     data: Table, grouping_columns: Sequence[str], mesh=None
 ) -> FrequenciesAndNumRows:
-    cols = [data.column(name) for name in grouping_columns]
-    valid = np.ones(data.num_rows, dtype=np.bool_)
-    for col in cols:
-        valid &= col.valid
+    """One batch's frequencies, as a `group_encode` span (each column's
+    dictionary encode and validity) and a `group_count` span (the
+    combined codes counted, the group keys gathered)."""
+    with observe.span("group_encode", cat="group") as sp:
+        if sp:
+            sp.set(rows=int(data.num_rows), columns=len(grouping_columns))
+        cols = [data.column(name) for name in grouping_columns]
+        valid = np.ones(data.num_rows, dtype=np.bool_)
+        for col in cols:
+            valid &= col.valid
+        encoded = [_column_key_values(col) for col in cols]
+    with observe.span("group_count", cat="group") as sp:
+        state = _count_groups(data, grouping_columns, valid, encoded, mesh)
+        if sp:
+            sp.set(rows=int(data.num_rows), groups=int(state.num_groups))
+    return state
 
-    encoded = [_column_key_values(col) for col in cols]
+
+def _count_groups(
+    data: Table, grouping_columns: Sequence[str], valid, encoded, mesh
+) -> FrequenciesAndNumRows:
     dims = [max(len(u), 1) for _, u in encoded]
 
     if not valid.any():
         return FrequenciesAndNumRows(
             list(grouping_columns),
-            [np.array([], dtype=object) for _ in cols],
+            [np.array([], dtype=object) for _ in encoded],
             np.array([], dtype=np.int64),
             data.num_rows,
         )
@@ -305,7 +327,7 @@ def _frequencies_of_batch(
     unraveled = np.unravel_index(unique_codes, dims)
     # per-column gather of group-key values: one fancy-index per column,
     # no Python loop over groups
-    key_columns = [encoded[j][1][unraveled[j]] for j in range(len(cols))]
+    key_columns = [encoded[j][1][unraveled[j]] for j in range(len(encoded))]
 
     return FrequenciesAndNumRows(
         list(grouping_columns), key_columns, counts, data.num_rows

@@ -169,7 +169,8 @@ class DataSource:
         producer is the DECODE STAGE of the stream pipeline
         (ops/pipeline.py): it adopts the consumer's trace context and
         reports per-batch `pipe_item` spans under a `pipe_stage` span,
-        which the run report's pipeline-occupancy section aggregates.
+        which the run report's pipeline-occupancy section aggregates; the
+        consumer's take from its queue is a `wait` span (`on="decode"`).
 
         Abandonment-safe (pinned by tests/test_pipeline_shutdown.py): if
         the consumer drops the generator early (an error mid-pass, a
@@ -260,7 +261,8 @@ class DataSource:
         produced_any = False
         try:
             while True:
-                item = q.get()
+                with _spans.span("wait", cat="wait", on="decode"):
+                    item = q.get()
                 if item is _SENTINEL:
                     break
                 produced_any = True

@@ -41,12 +41,14 @@ def _events_for(
     pid: int,
     tid_map: Dict[int, int],
     out: List[dict],
+    root: bool = False,
 ) -> None:
     tid = tid_map.setdefault(span.tid, len(tid_map))
     ts = max((span.t0 - epoch) * 1e6, 0.0)
     end = max((span.t1 - epoch) * 1e6, ts)
     args = {k: v for k, v in span.attrs.items()}
-    args["cpu_ms"] = round(span.cpu_s * 1e3, 3)
+    if root:  # the process CPU clock is read on roots only
+        args["cpu_ms"] = round(span.cpu_s * 1e3, 3)
     begin = {
         "ph": "B",
         "ts": ts,
@@ -83,7 +85,7 @@ def chrome_trace(
     events: List[dict] = []
     tid_map: Dict[int, int] = {}
     for root in roots:
-        _events_for(root, epoch, pid, tid_map, events)
+        _events_for(root, epoch, pid, tid_map, events, root=True)
     meta = {"process_index": pid}
     if metadata:
         meta.update(metadata)

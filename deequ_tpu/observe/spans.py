@@ -1,7 +1,9 @@
 """Hierarchical spans and the thread-local trace context stack.
 
-A `Span` is one timed region (wall via perf_counter, CPU via
-process_time) with attributes and children. A `Tracer` owns a forest of
+A `Span` is one timed region (wall via perf_counter) with attributes
+and children. Roots, and the root of every traced run, also read the
+process CPU clock (process_time): it counts every thread, so under a
+child span it would say nothing of that span. A `Tracer` owns a forest of
 root spans plus run-level counters; `tracing()` installs one on the
 current thread, `span()` opens a child of whatever is innermost.
 
@@ -109,13 +111,15 @@ class Span:
                 sink = parent.children if parent is not None else tracer.roots
                 sink.append(self)
             st.append((tracer, self))
-        self.cpu0 = _process_time()
+        if parent is None or self.cat == "run":
+            self.cpu0 = _process_time()
         self.t0 = _perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         self.t1 = _perf_counter()
-        self.cpu1 = _process_time()
+        if self.cpu0:
+            self.cpu1 = _process_time()
         if exc_type is not None:
             self.attrs.setdefault("error", exc_type.__name__)
         st = getattr(_local, "stack", None)
@@ -182,7 +186,7 @@ class Tracer:
     exporter subtracts timestamps from, and run-level counters kept
     bit-identical to `ExecutionStats` (observe.counters feeds both)."""
 
-    __slots__ = ("lock", "roots", "epoch", "epoch_unix", "counters", "labels")
+    __slots__ = ("lock", "roots", "epoch", "epoch_unix", "counters")
 
     def __init__(self) -> None:
         self.lock = threading.Lock()
@@ -190,13 +194,10 @@ class Tracer:
         self.epoch = _perf_counter()
         self.epoch_unix = time.time()
         self.counters: Dict[str, int] = {}
-        self.labels: List[str] = []
 
-    def count(self, name: str, n: int = 1, label: Optional[str] = None) -> None:
+    def count(self, name: str, n: int = 1) -> None:
         with self.lock:
             self.counters[name] = self.counters.get(name, 0) + n
-            if label is not None:
-                self.labels.append(label)
         s = current_span()
         if s is not None:
             s.add(name, n)

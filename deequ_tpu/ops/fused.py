@@ -223,7 +223,8 @@ def wire_shifts(sticky) -> Dict[str, float]:
 def pack_batch_inputs(
     built_items, padded: int, dtype, sticky=None, num_rows=None, prepacked=None
 ):
-    """Build the minimal wire format for one batch.
+    """Build the minimal wire format for one batch, as a `pack` span
+    (category `dispatch`) with one `h2d` child per device put.
 
     Fewer bytes over the host link, whatever its bandwidth:
       * bool masks  -> bitpacked, 1 bit/row
@@ -250,6 +251,15 @@ def pack_batch_inputs(
     (const->bits, narrow int->wider int), bounding recompiles at 2 per key
     instead of one per distinct batch data range.
     """
+    with observe.span("pack", cat="dispatch") as sp:
+        if sp and num_rows is not None:
+            sp.set(rows=int(num_rows))
+        return _pack_batch_inputs(
+            built_items, padded, dtype, sticky, num_rows, prepacked
+        )
+
+
+def _pack_batch_inputs(built_items, padded, dtype, sticky, num_rows, prepacked):
     if sticky is None:
         sticky = {}
     if prepacked is None:
@@ -328,14 +338,20 @@ def pack_batch_inputs(
         buf = np.zeros(len(entries) * row_len, dtype=dtype_name)
         for i, (_key, _kind, arr) in enumerate(entries):
             buf[i * row_len : i * row_len + len(arr)] = arr
-        packed_inputs[group_name] = jnp.asarray(buf)
+        packed_inputs[group_name] = _h2d_put(buf)
         groups.append((group_name, tuple((e[0], e[1]) for e in entries)))
     if const_keys:
-        packed_inputs["__nrows"] = jnp.asarray(
-            np.array([num_rows or 0], dtype=np.int32)
-        )
+        packed_inputs["__nrows"] = _h2d_put(np.array([num_rows or 0], dtype=np.int32))
     layout = (tuple(groups), tuple(sorted(const_keys)), padded)
     return packed_inputs, layout
+
+
+def _h2d_put(buf: np.ndarray):
+    """One host-to-device put of a packed wire buffer, as an `h2d` span."""
+    with observe.span("h2d", cat="dispatch") as sp:
+        if sp:
+            sp.set(bytes=int(buf.nbytes))
+        return jnp.asarray(buf)
 
 
 # -- pure plan construction ---------------------------------------------------
@@ -1609,11 +1625,14 @@ class HostInputs(dict):
         spec = self._specs.get(key)
         if spec is None:
             raise KeyError(key)
-        try:
-            value = np.asarray(spec.build(self.batch))
-        except Exception as e:  # noqa: BLE001
-            self.build_errors[key] = e
-            raise
+        with observe.span("build", cat="build") as sp:
+            if sp:
+                sp.set(key=key.partition(":")[0], rows=int(self.batch.num_rows))
+            try:
+                value = np.asarray(spec.build(self.batch))
+            except Exception as e:  # noqa: BLE001
+                self.build_errors[key] = e
+                raise
         self[key] = value
         return value
 
@@ -2235,32 +2254,36 @@ class FusedScanPass:
 
         if plan.any_members:
             live_idx = merge_idx + assisted_idx + host_idx + host_assisted_idx
-            prune = plan_row_group_prune(
-                table, [self.analyzers[i] for i in live_idx]
-            )
-            if prune is not None:
-                # spec elision must precede column pruning so a
-                # constant-mask where's filter columns drop out of decode
-                table = apply_prune_plan(table, prune, specs)
-            table = prune_table_columns(table, specs)
-            if self._forensics is not None:
-                # coordinate map + prune provenance come from the PRUNED
-                # source: scan offsets then map to surviving row groups
-                self._forensics.note_table(table)
-            # decode routing comes last: it classifies exactly the
-            # columns that survived pruning (with_columns returns a new
-            # source, so the fast set must attach to the final view)
-            decode_plan = plan_decode_fastpath(
-                table,
-                specs,
-                member_plan=plan,
-                batch_size=self.batch_size,
-                analyzers=[self.analyzers[i] for i in live_idx],
-            )
-            if decode_plan is not None:
-                table = apply_decode_plan(table, decode_plan)
+            # the source's plan: row-group and column pruning, decode
+            # routing, and the source views they attach (a Parquet
+            # source reads its footer for each)
+            with observe.span("plan_source", cat="plan"):
+                prune = plan_row_group_prune(
+                    table, [self.analyzers[i] for i in live_idx]
+                )
+                if prune is not None:
+                    # spec elision must precede column pruning so a
+                    # constant-mask where's filter columns drop out of decode
+                    table = apply_prune_plan(table, prune, specs)
+                table = prune_table_columns(table, specs)
                 if self._forensics is not None:
-                    self._forensics.note_decode_plan(decode_plan)
+                    # coordinate map + prune provenance come from the PRUNED
+                    # source: scan offsets then map to surviving row groups
+                    self._forensics.note_table(table)
+                # decode routing comes last: it classifies exactly the
+                # columns that survived pruning (with_columns returns a new
+                # source, so the fast set must attach to the final view)
+                decode_plan = plan_decode_fastpath(
+                    table,
+                    specs,
+                    member_plan=plan,
+                    batch_size=self.batch_size,
+                    analyzers=[self.analyzers[i] for i in live_idx],
+                )
+                if decode_plan is not None:
+                    table = apply_decode_plan(table, decode_plan)
+                    if self._forensics is not None:
+                        self._forensics.note_decode_plan(decode_plan)
             merge_analyzers = [self.analyzers[i] for i in merge_idx]
             assisted = [self.analyzers[i] for i in assisted_idx]
             host_members = [(i, self.analyzers[i]) for i in host_idx]
@@ -2819,17 +2842,19 @@ class FusedScanPass:
                                 device_error = device_exc
                             elif packed_inputs is not None:
                                 try:
-                                    fused, meta_box = get_fused_fn(
-                                        analyzers, assisted, layout
-                                    )
-                                    runtime.record_launch()
+                                    # the launch counts as dispatch, as on
+                                    # the serial path; the pack and puts
+                                    # ran in the prep stage's `dispatch`
+                                    with observe.span("launch", cat="dispatch"):
+                                        fused, meta_box = get_fused_fn(
+                                            analyzers, assisted, layout
+                                        )
+                                        runtime.record_launch()
+                                        out = fused(packed_inputs)
                                     # async dispatch; submit folds the
                                     # PREVIOUS batch (async D2H landed)
                                     # while the device crunches this one
-                                    fold.submit(
-                                        fused(packed_inputs), meta_box,
-                                        host_ctx=built,
-                                    )
+                                    fold.submit(out, meta_box, host_ctx=built)
                                 except Exception as e:  # noqa: BLE001
                                     device_error = e
                             if device_error is not None:

@@ -36,7 +36,10 @@ tools/lint.py bans them in this file and in data/source.py). Stage
 threads adopt the dispatching thread's trace context
 (`observe.attached`) and report a `pipe_stage` span with one
 `pipe_item` child per batch — what the run report's pipeline-occupancy
-section aggregates; with tracing off, spans hit the no-op fast path.
+section aggregates. The consumer's blocking take from a stage's queue
+is a `wait` span (`on` = the stage it waits for), so time a consumer
+spends idle is named and not left in its stage span's self time. With
+tracing off, spans hit the no-op fast path.
 
 Occupancy attribution under decode-to-wire fusion
 (`DEEQU_TPU_WIRE_FUSED`): a fused column's bit-packing and value
@@ -189,7 +192,8 @@ def staged(
     thread.start()
     try:
         while True:
-            out = q.get()
+            with observe.span("wait", cat="wait", on=name):
+                out = q.get()
             if out is _SENTINEL:
                 break
             yield out
