@@ -9,7 +9,9 @@ spills instead of OOMing. This module is the engine-level equivalent:
     partials in RAM until the accumulated group count crosses a cap
     (DEEQU_TPU_MAX_GROUPS_IN_MEMORY, default 2M groups), then switches
     to hash-partitioned disk spill: each partial's groups are routed by
-    a stable 64-bit key hash into one of N partition files.
+    a stable 64-bit key hash into one of N partition files. Typed key
+    columns (int, bool, float) route, spill and compact as typed arrays;
+    string or object columns take the text hash and the pandas merge.
   * `finalize()` compacts each partition once (all chunks of a
     partition merge together; a partition holds ~#groups/N distinct
     keys, so peak memory is O(cap + batch + groups/N), never O(groups))
@@ -54,13 +56,67 @@ def _hash_key_rows(key_columns: Sequence[np.ndarray]) -> np.ndarray:
     """Stable uint64 hash per group row (combines all key columns).
     Stability across batches/processes matters: the same key must land
     in the same partition everywhere, so merges stay partition-local."""
-    from deequ_tpu.ops.strings import hash_strings
-
     acc = np.full(len(key_columns[0]), np.uint64(0x9E3779B97F4A7C15))
     for kc in key_columns:
-        h = hash_strings(np.asarray(kc).astype(str).astype(object))
-        acc = (acc * np.uint64(0xC2B2AE3D27D4EB4F)) ^ h
+        acc = (acc * np.uint64(0xC2B2AE3D27D4EB4F)) ^ _key_hashes(kc)
     return acc
+
+
+def _key_hashes(kc: np.ndarray) -> np.ndarray:
+    """uint64 per key VALUE, whatever array carries it: a typed column
+    and an object column holding the same number hash alike (so one
+    writer never splits a key across partitions), numbers by a 64-bit
+    mix of their value, strings by their text."""
+    from deequ_tpu.analyzers.frequency import typed_from_values, typed_key_column
+    from deequ_tpu.ops.strings import hash_strings
+
+    if kc.dtype != object:  # a state's key columns are typed or objects
+        return _mix64(_value_bits(typed_key_column(kc)))
+    values = kc.tolist()
+    types = set(map(type, values))
+    typed = typed_from_values(values, types)
+    if typed is not None:
+        return _mix64(_value_bits(typed))
+    h = hash_strings(kc.astype(str).astype(object))
+    if not all(issubclass(t, str) for t in types):
+        # mixed families (slow path): each number hashes as a typed
+        # column would carry it; ints beyond int64 keep their text hash
+        for family, dtype in (
+            ((bool, np.bool_, int, np.integer), np.int64),
+            ((float, np.floating), np.float64),
+        ):
+            idx = [
+                i
+                for i, v in enumerate(values)
+                if isinstance(v, family)
+                and (dtype is np.float64 or -(1 << 63) <= v < (1 << 63))
+            ]
+            if idx:
+                picked = np.array([values[i] for i in idx], dtype=dtype)
+                h[idx] = _mix64(_value_bits(picked))
+    return h
+
+
+def _value_bits(typed: np.ndarray) -> np.ndarray:
+    """uint64 per value, equal for values Python holds equal: bools and
+    integral floats as the int they equal (so -0.0 is 0), every NaN as
+    one NaN."""
+    if typed.dtype.kind != "f":
+        return typed.astype(np.int64, copy=False).view(np.uint64)
+    integral = (np.floor(typed) == typed) & (typed >= -(2.0**63)) & (typed < 2.0**63)
+    canon = typed.copy()
+    canon[np.isnan(canon)] = np.nan
+    bits = canon.view(np.uint64)
+    bits[integral] = typed[integral].astype(np.int64).view(np.uint64)
+    return bits
+
+
+def _mix64(z: np.ndarray) -> np.ndarray:
+    """splitmix64's finalizer: every input bit reaches the low bits the
+    partition index is taken from."""
+    z = (z ^ (z >> np.uint64(30))) * np.uint64(0xBF58476D1CE4E5B9)
+    z = (z ^ (z >> np.uint64(27))) * np.uint64(0x94D049BB133111EB)
+    return z ^ (z >> np.uint64(31))
 
 
 class _SpillWriter:
@@ -106,9 +162,10 @@ class _SpillWriter:
             stop = min(start + _ROUTE_CHUNK, len(partial.counts))
             kcs = [kc[start:stop] for kc in key_columns]
             counts = partial.counts[start:stop]
-            parts = (
-                _hash_key_rows(kcs) % np.uint64(self.n_partitions)
-            ).astype(np.int64)
+            # an 8- or 16-bit partition index stable-sorts by radix, O(chunk)
+            parts = (_hash_key_rows(kcs) % np.uint64(self.n_partitions)).astype(
+                np.min_scalar_type(self.n_partitions - 1)
+            )
             order = np.argsort(parts, kind="stable")
             sorted_parts = parts[order]
             boundaries = np.searchsorted(
@@ -138,9 +195,13 @@ class _SpillWriter:
 
     def finalize(self) -> "SpilledFrequencies":
         """Compact each partition to one chunk; record exact group count."""
-        from deequ_tpu.analyzers.frequency import FrequenciesAndNumRows
+        from deequ_tpu.analyzers.frequency import (
+            FrequenciesAndNumRows,
+            concat_key_chunks,
+        )
 
         num_groups = 0
+        typed = True
         # one directory scan, bucketed by partition prefix
         by_partition: dict = {}
         for fn in os.listdir(self.directory):
@@ -160,7 +221,7 @@ class _SpillWriter:
             merged = FrequenciesAndNumRows(
                 self.columns,
                 [
-                    np.concatenate([kc[j] for kc in key_chunks])
+                    concat_key_chunks([kc[j] for kc in key_chunks])
                     for j in range(len(self.columns))
                 ],
                 np.concatenate(count_chunks),
@@ -169,6 +230,7 @@ class _SpillWriter:
             if len(chunk_files) > 1:
                 merged = merged.compacted()
             num_groups += merged.num_groups
+            typed = typed and merged.typed
             with open(
                 os.path.join(self.directory, f"part{p:03d}.pkl"), "wb"
             ) as f:
@@ -182,7 +244,12 @@ class _SpillWriter:
         # ownership of the directory passes to the state object
         self._cleanup.detach()
         return SpilledFrequencies(
-            self.columns, self.directory, self.n_partitions, self.num_rows, num_groups
+            self.columns,
+            self.directory,
+            self.n_partitions,
+            self.num_rows,
+            num_groups,
+            typed,
         )
 
 
@@ -202,12 +269,15 @@ class SpilledFrequencies(State):
         n_partitions: int,
         num_rows: int,
         num_groups: int,
+        typed: bool,
     ):
         self.columns = list(columns)
         self.directory = directory
         self.n_partitions = n_partitions
         self.num_rows = int(num_rows)
         self.num_groups = int(num_groups)
+        # every partition compacted on typed key columns
+        self.typed = bool(typed)
         self._cleanup = weakref.finalize(
             self, shutil.rmtree, directory, ignore_errors=True
         )
@@ -264,7 +334,10 @@ class SpilledFrequencies(State):
     def merge(self, other) -> "SpilledFrequencies":
         """Semigroup merge with either state flavor: re-partition both
         sides into a fresh spill (partition-local compaction keeps the
-        memory bound). Neither operand is mutated."""
+        memory bound). Neither operand is mutated. Sides may carry a
+        key column differently (typed here, objects from a loaded state):
+        routing hashes values, so a key meets itself in one partition, and
+        compaction goes typed where the object side converts exactly."""
         writer = _SpillWriter(self.columns, self.n_partitions)
         for part in self.partitions():
             writer.append(part, include_rows=False)

@@ -15,6 +15,7 @@ reference's null-safe outer join (GroupingAnalyzers.scala:128-148).
 
 from __future__ import annotations
 
+import math
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -37,9 +38,11 @@ class FrequenciesAndNumRows(State):
     """Group keys + counts + overall #rows
     (reference: GroupingAnalyzers.scala:124-157).
 
-    Keys are stored columnar (one object array per grouping column,
-    aligned with ``counts``) so merges stay vectorized; ``keys`` exposes
-    the row-tuple view lazily for consumers that want it.
+    Keys are stored columnar (one array per grouping column, aligned
+    with ``counts``) so merges stay vectorized: int, bool and float key
+    columns stay typed numpy arrays, anything else is an object array.
+    ``keys`` exposes the row-tuple view (Python scalars) lazily for
+    consumers that want it.
     """
 
     __slots__ = ("columns", "key_columns", "counts", "num_rows", "_keys")
@@ -53,7 +56,10 @@ class FrequenciesAndNumRows(State):
         if len(keys) == len(self.columns) and all(
             isinstance(k, np.ndarray) for k in keys
         ):
-            self.key_columns = [np.asarray(k, dtype=object) for k in keys]
+            self.key_columns = [
+                k if k.dtype.kind in _TYPED_KINDS else k.astype(object, copy=False)
+                for k in keys
+            ]
         else:
             n = len(keys)
             self.key_columns = [
@@ -79,6 +85,11 @@ class FrequenciesAndNumRows(State):
     def num_groups(self) -> int:
         return len(self.counts)
 
+    @property
+    def typed(self) -> bool:
+        """Every key column is typed, so merges skip pandas and objects."""
+        return all(kc.dtype.kind in _TYPED_KINDS for kc in self.key_columns)
+
     def merge(self, other) -> "FrequenciesAndNumRows":
         if getattr(other, "is_spilled", False):
             # spilled ⊕ in-memory commutes; the spilled side knows how
@@ -97,7 +108,7 @@ class FrequenciesAndNumRows(State):
             ]
         key_columns, counts = _group_sum(
             [
-                np.concatenate([self.key_columns[j], other_cols[j]])
+                concat_key_chunks([self.key_columns[j], other_cols[j]])
                 for j in range(len(self.columns))
             ],
             np.concatenate([self.counts, other.counts]),
@@ -133,12 +144,71 @@ class FrequenciesAndNumRows(State):
         )
 
 
+# a key column of these dtype kinds stays a typed array, as this dtype
+_TYPED_DTYPES = {"b": np.bool_, "i": np.int64, "f": np.float64}
+_TYPED_KINDS = "".join(_TYPED_DTYPES)
+
+
+def typed_key_column(kc: np.ndarray) -> Optional[np.ndarray]:
+    """`kc` as an int64, bool or float64 array where that is exact, else
+    None. An object array converts only when every element is of one
+    family: all bools, all ints within int64, or all floats."""
+    kind = kc.dtype.kind
+    if kind in _TYPED_DTYPES:
+        return kc.astype(_TYPED_DTYPES[kind], copy=False)
+    if kind != "O" or not len(kc):
+        return None
+    values = kc.tolist()
+    return typed_from_values(values, set(map(type, values)))
+
+
+def typed_from_values(values: list, types: set) -> Optional[np.ndarray]:
+    """The typed array of `values` (whose element types are `types`)
+    where one family holds them all exactly, else None."""
+    if all(issubclass(t, (bool, np.bool_)) for t in types):
+        return np.array(values, dtype=np.bool_)
+    if all(
+        issubclass(t, (int, np.integer)) and not issubclass(t, bool) for t in types
+    ):
+        try:
+            return np.array(values, dtype=np.int64)
+        except OverflowError:
+            return None
+    if all(issubclass(t, (float, np.floating)) for t in types):
+        return np.array(values, dtype=np.float64)
+    return None
+
+
+def concat_key_chunks(chunks: Sequence[np.ndarray]) -> np.ndarray:
+    """One key column from chunks of it: typed when every non-empty chunk
+    is typed or converts exactly to the same typed dtype, else object
+    (the pandas merge then groups by Python equality)."""
+    live = [c for c in chunks if len(c)] or list(chunks[:1])
+    if all(c.dtype == object for c in live):
+        return np.concatenate(live)
+    typed = [typed_key_column(c) for c in live]
+    if all(t is not None for t in typed) and len({t.dtype for t in typed}) == 1:
+        return np.concatenate(typed)
+    return np.concatenate([c.astype(object) for c in live])
+
+
+def _canonical_floats(x: np.ndarray) -> np.ndarray:
+    """-0.0 as 0.0 and every NaN as one NaN, so equal keys have equal
+    bits (pandas `dropna=False` groups them the same way)."""
+    out = np.where(x == 0.0, 0.0, x).astype(np.float64, copy=False)
+    out[np.isnan(out)] = np.nan
+    return out
+
+
 def _group_sum(
     key_columns: List[np.ndarray], counts: np.ndarray
 ) -> Tuple[List[np.ndarray], np.ndarray]:
-    """C-hash group-by summing counts over identical key rows — the
-    vectorized form of the reference's null-safe outer join + count sum
-    (GroupingAnalyzers.scala:128-148); no Python loop over groups."""
+    """Group-by summing counts over identical key rows — the vectorized
+    form of the reference's null-safe outer join + count sum
+    (GroupingAnalyzers.scala:128-148); no Python loop over groups.
+    Typed key columns sort; any object column takes pandas' C hash."""
+    if key_columns and all(kc.dtype.kind in _TYPED_KINDS for kc in key_columns):
+        return _typed_group_sum(key_columns, counts)
     import pandas as pd
 
     n_cols = len(key_columns)
@@ -162,6 +232,54 @@ def _group_sum(
             for j in range(n_cols)
         ]
     return out_keys, grouped.to_numpy(dtype=np.int64)
+
+
+def _typed_group_sum(
+    key_columns: List[np.ndarray], counts: np.ndarray
+) -> Tuple[List[np.ndarray], np.ndarray]:
+    """Sort the key rows, mark where a key changes, sum each run with
+    `np.add.reduceat`. Group order is the sort's, which no consumer
+    depends on."""
+    cols = [
+        _canonical_floats(kc) if kc.dtype.kind == "f" else kc for kc in key_columns
+    ]
+    counts = np.asarray(counts, dtype=np.int64)
+    if not len(counts):
+        return [c[:0] for c in cols], counts
+    packed = _packed_key(cols)
+    # floats sort and compare by their (canonical) bits: equal keys meet
+    sort_keys = (
+        [packed]
+        if packed is not None
+        else [c.view(np.int64) if c.dtype.kind == "f" else c for c in cols]
+    )
+    if len(sort_keys) == 1:
+        order = np.argsort(sort_keys[0])
+    else:
+        order = np.lexsort(sort_keys[::-1])
+    change = np.zeros(len(order) - 1, dtype=np.bool_)
+    for k in sort_keys:
+        ks = k[order]
+        change |= ks[1:] != ks[:-1]
+    starts = np.flatnonzero(np.concatenate(([True], change)))
+    firsts = order[starts]
+    return [c[firsts] for c in cols], np.add.reduceat(counts[order], starts)
+
+
+def _packed_key(cols: List[np.ndarray]) -> Optional[np.ndarray]:
+    """Two or more int/bool key columns as one int64 (mixed radix over
+    each column's range), where the ranges' product fits; else None.
+    One argsort of it beats a lexsort of the columns several times."""
+    if len(cols) < 2 or any(c.dtype.kind not in "bi" for c in cols):
+        return None
+    los = [int(c.min()) for c in cols]
+    spans = [int(c.max()) - lo + 1 for c, lo in zip(cols, los)]
+    if math.prod(spans) > np.iinfo(np.int64).max:
+        return None
+    key = np.zeros(len(cols[0]), dtype=np.int64)
+    for c, lo, span in zip(cols, los, spans):
+        key = key * span + (c.astype(np.int64) - lo)
+    return key
 
 
 def top_n_order(keys: np.ndarray, counts: np.ndarray, n: int) -> np.ndarray:
@@ -199,17 +317,19 @@ def top_n_order(keys: np.ndarray, counts: np.ndarray, n: int) -> np.ndarray:
 
 
 def _column_key_values(col) -> Tuple[np.ndarray, np.ndarray]:
-    """(codes, uniques) with uniques as python-friendly scalars."""
+    """(codes, uniques): LONG, BOOLEAN and DOUBLE/DECIMAL uniques as
+    typed int64 / bool / float64 arrays, anything else as objects."""
     codes, uniques = col.dict_encode()
-    if col.ctype == ColumnType.LONG:
-        uniques = np.array([int(u) for u in uniques], dtype=object)
-    elif col.ctype in (ColumnType.DOUBLE, ColumnType.DECIMAL):
-        uniques = np.array([float(u) for u in uniques], dtype=object)
-    elif col.ctype == ColumnType.BOOLEAN:
-        uniques = np.array([bool(u) for u in uniques], dtype=object)
-    else:
-        uniques = np.asarray(uniques, dtype=object)
-    return codes, uniques
+    dtype = _KEY_DTYPES.get(col.ctype, object)
+    return codes, np.asarray(uniques, dtype=dtype)
+
+
+_KEY_DTYPES = {
+    ColumnType.LONG: np.int64,
+    ColumnType.BOOLEAN: np.bool_,
+    ColumnType.DOUBLE: np.float64,
+    ColumnType.DECIMAL: np.float64,
+}
 
 
 def compute_frequencies(
@@ -254,11 +374,19 @@ def _compute_frequencies(
             with observe.span("group_merge", cat="group") as sp:
                 acc.add(partial)
                 if sp:
-                    sp.set(groups=int(partial.num_groups), spilled=acc.spilled)
+                    sp.set(
+                        groups=int(partial.num_groups),
+                        spilled=acc.spilled,
+                        typed=partial.typed,
+                    )
         with observe.span("group_merge", cat="group") as sp:
             state = acc.finalize()
             if sp:
-                sp.set(groups=int(state.num_groups), spilled=acc.spilled)
+                sp.set(
+                    groups=int(state.num_groups),
+                    spilled=acc.spilled,
+                    typed=state.typed,
+                )
         if num_rows is not None:
             state.num_rows = num_rows
         return state
@@ -303,7 +431,7 @@ def _count_groups(
     if not valid.any():
         return FrequenciesAndNumRows(
             list(grouping_columns),
-            [np.array([], dtype=object) for _ in encoded],
+            [uniques[:0] for _, uniques in encoded],
             np.array([], dtype=np.int64),
             data.num_rows,
         )
@@ -326,7 +454,7 @@ def _count_groups(
 
     unraveled = np.unravel_index(unique_codes, dims)
     # per-column gather of group-key values: one fancy-index per column,
-    # no Python loop over groups
+    # no Python loop over groups (typed uniques give typed key columns)
     key_columns = [encoded[j][1][unraveled[j]] for j in range(len(encoded))]
 
     return FrequenciesAndNumRows(

@@ -309,6 +309,184 @@ def test_spilled_state_serializes_for_multihost_envelope(high_card_parquet):
     assert a == pytest.approx(b, rel=0, abs=0)
 
 
+def _key_counts(state) -> dict:
+    """{key tuple: count} over every partition of a state; a key met
+    twice (in two partitions, or twice in one) fails. NaN keys compare
+    as one key."""
+    parts = (
+        list(state.partitions()) if getattr(state, "is_spilled", False) else [state]
+    )
+    out = {}
+    for part in parts:
+        for key, count in zip(part.keys, part.counts.tolist()):
+            key = tuple("NaN" if v != v else v for v in key)
+            assert key not in out, key
+            out[key] = count
+    return out
+
+
+@pytest.fixture(scope="module")
+def typed_keys_parquet(tmp_path_factory):
+    """Key columns of each kind, every one able to spill at the 10k cap:
+    a near-unique int64, (orderkey, linenumber) shaped like TPC-H
+    lineitem, a bool, a float64 whose zeros are -0.0 in the first half
+    of the file and 0.0 in the second (batches disagree on the sign),
+    and a near-unique string."""
+    import pyarrow as pa
+    import pyarrow.parquet as pq
+
+    rng = np.random.default_rng(17)
+    ik = rng.permutation(N_ROWS).astype(np.int64)
+    ik[::1000] = -1
+    lines = rng.integers(1, 8, N_ROWS)
+    orderkey = np.repeat(np.arange(1, N_ROWS + 1, dtype=np.int64) * 4, lines)[:N_ROWS]
+    linenumber = np.concatenate([np.arange(1, n + 1) for n in lines])[:N_ROWS]
+    f = np.round(rng.random(N_ROWS) * 40_000.0, 1)
+    f[::7] = 0.0
+    f[: N_ROWS // 2][::7] = -0.0
+    f[5::11] = np.nan
+    path = tmp_path_factory.mktemp("typed") / "typed_keys.parquet"
+    pq.write_table(
+        pa.table({
+            "ik": ik,
+            "ok": orderkey,
+            "ln": linenumber,
+            "flag": rng.random(N_ROWS) < 0.5,
+            "f": f,
+            "s": pa.array([f"s{v}" for v in ik]),
+        }),
+        str(path),
+        row_group_size=20_000,
+    )
+    return str(path)
+
+
+@pytest.mark.parametrize("columns, typed", [
+    (["ik"], True),
+    (["ok", "ln"], True),
+    (["flag", "ik"], True),
+    (["f"], True),
+    (["s"], False),
+], ids=["int64", "lineitem_pair", "bool_int64", "float64", "string"])
+def test_spill_by_key_kind_matches_in_memory(typed_keys_parquet, columns, typed):
+    """Every key kind spills at the 10k cap and gives the in-memory
+    per-key counts and metrics; int, bool and float keys stay typed
+    through routing and compaction, strings take the object path."""
+    source = ParquetSource(typed_keys_parquet, batch_rows=1 << 14)
+    spilled = compute_frequencies(source, columns)
+    in_memory = compute_frequencies(Table.from_parquet(typed_keys_parquet), columns)
+    assert isinstance(spilled, SpilledFrequencies)
+    assert spilled.typed is typed and in_memory.typed is typed
+    assert _key_counts(spilled) == _key_counts(in_memory)
+    assert spilled.num_groups == in_memory.num_groups
+
+    analyzers = [
+        Uniqueness(columns),
+        Distinctness(columns),
+        UniqueValueRatio(columns),
+        CountDistinct(columns),
+    ] + ([Entropy(columns[0])] if len(columns) == 1 else [MutualInformation(*columns)])
+    ctx_stream = AnalysisRunner.do_analysis_run(source, analyzers, engine="single")
+    ctx_mem = AnalysisRunner.do_analysis_run(
+        Table.from_parquet(typed_keys_parquet), analyzers, engine="single"
+    )
+    for analyzer in analyzers:
+        assert ctx_stream.metric_map[analyzer].value.get() == pytest.approx(
+            ctx_mem.metric_map[analyzer].value.get(), rel=1e-12
+        ), analyzer
+
+
+def _lineitem_state(first_order: int, n_orders: int, seed: int):
+    rng = np.random.default_rng(seed)
+    lines = rng.integers(1, 8, n_orders)
+    orderkey = np.repeat(np.arange(first_order, first_order + n_orders), lines)
+    linenumber = np.concatenate([np.arange(1, n + 1) for n in lines])
+    counts = rng.integers(1, 4, len(orderkey))
+    return FrequenciesAndNumRows(
+        ["l_linenumber", "l_orderkey"],
+        [linenumber.astype(np.int64), orderkey.astype(np.int64)],
+        counts,
+        int(counts.sum()),
+    )
+
+
+@pytest.mark.parametrize("other_orders", [1_000, 6_000], ids=["object_in_memory", "object_spilled"])
+@pytest.mark.parametrize("typed_first", [True, False], ids=["typed_first", "object_first"])
+def test_spilled_merge_of_typed_and_object_keys(tmp_path, other_orders, typed_first):
+    """A typed spilled state merged with the same keys carried as objects
+    (a state_provider round trip) gives the in-memory merge's per-key
+    counts, with no key in two partitions, in either order."""
+    from deequ_tpu.analyzers.state_provider import FileSystemStateProvider
+
+    typed_mem = _lineitem_state(1, 8_000, seed=1)
+    other_mem = _lineitem_state(5_000, other_orders, seed=2)  # overlaps 5000..
+    acc = GroupCountAccumulator(typed_mem.columns, max_groups_in_memory=5_000)
+    half = typed_mem.num_groups // 2
+    for sl in (slice(0, half), slice(half, None)):
+        acc.add(FrequenciesAndNumRows(
+            typed_mem.columns,
+            [kc[sl] for kc in typed_mem.key_columns],
+            typed_mem.counts[sl],
+            int(typed_mem.counts[sl].sum()),
+        ))
+    typed_spilled = acc.finalize()
+    assert isinstance(typed_spilled, SpilledFrequencies) and typed_spilled.typed
+
+    provider = FileSystemStateProvider(str(tmp_path))
+    analyzer = Uniqueness(other_mem.columns)
+    provider.persist(analyzer, other_mem)
+    other = provider.load(analyzer)
+    carried = next(other.partitions()) if getattr(other, "is_spilled", False) else other
+    assert carried.key_columns[0].dtype == object  # the state came back boxed
+    assert getattr(other, "is_spilled", False) is (other_orders > 1_000)
+
+    merged = typed_spilled.merge(other) if typed_first else other.merge(typed_spilled)
+    assert isinstance(merged, SpilledFrequencies)
+    assert merged.typed  # the object side converted exactly
+    want = typed_mem.merge(other_mem)
+    assert _key_counts(merged) == _key_counts(want)
+    assert merged.num_groups == want.num_groups
+    assert merged.num_rows == want.num_rows
+
+
+@pytest.mark.parametrize("typed, boxed", [
+    (np.array([5, -1, 1 << 40], dtype=np.int64), [5, -1, 1 << 40]),
+    (np.array([True, False]), [True, False]),
+    (np.array([-0.0, 0.0, np.nan, 2.5, 3.0]), [0.0, -0.0, float("nan"), 2.5, 3.0]),
+], ids=["int64", "bool", "float64"])
+def test_route_hash_follows_the_value_not_the_carrier(typed, boxed):
+    from deequ_tpu.analyzers.freq_spill import _key_hashes
+
+    want = _key_hashes(typed)
+    assert np.array_equal(_key_hashes(np.array(boxed, dtype=object)), want)
+    # in a column mixing families a number still hashes by its value
+    mixed = _key_hashes(np.array(boxed + ["text"], dtype=object))
+    assert np.array_equal(mixed[:-1], want)
+    if typed.dtype.kind == "f":  # -0.0 and 0.0, NaN and NaN: one key each
+        assert want[0] == want[1] and want[2] == _key_hashes(np.array([-np.nan]))[0]
+
+
+@pytest.mark.parametrize("columns", [
+    [np.array([3, 1, 3, 2, 1, 3], dtype=np.int64)],
+    [np.array([1, 1, 2, 2, 1, 1]), np.array([7, 7, 7, 8, 7, 7], dtype=np.int64)],
+    [np.array([True, False, True, True, False, False])],
+    [np.array([-0.0, 0.0, np.nan, 1.5, -np.nan, 1.5])],
+    [np.array([1.0, 1.0, np.nan, np.nan, 0.0, -0.0]), np.array([True, True, False, False, True, True])],
+], ids=["int64", "int_pair", "bool", "float64", "float_bool"])
+def test_typed_group_sum_matches_the_pandas_merge(columns):
+    """The typed sort-and-reduce groups as pandas `dropna=False` does over
+    the same keys as objects: -0.0 with 0.0, every NaN together."""
+    from deequ_tpu.analyzers.frequency import _group_sum
+
+    counts = np.arange(1, len(columns[0]) + 1, dtype=np.int64)
+    typed_keys, typed_counts = _group_sum(columns, counts)
+    boxed_keys, boxed_counts = _group_sum([c.astype(object) for c in columns], counts)
+    assert all(k.dtype != object for k in typed_keys)
+    assert _key_counts(FrequenciesAndNumRows(["c"] * len(columns), typed_keys, typed_counts, 0)) == (
+        _key_counts(FrequenciesAndNumRows(["c"] * len(columns), boxed_keys, boxed_counts, 0))
+    )
+
+
 def test_spilled_state_persists_via_state_provider(tmp_path, high_card_parquet):
     from deequ_tpu.analyzers.state_provider import FileSystemStateProvider
 

@@ -170,6 +170,29 @@ def test_frequency_pass_phases(parquet_path, streamed):
         assert merges[-1].attrs["groups"] == state.num_groups
 
 
+@pytest.mark.parametrize("columns, typed", [
+    (["key"], True),
+    (["qty", "key"], True),
+    (["mode"], False),
+    (["mode", "key"], False),
+])
+@pytest.mark.parametrize("spill", [False, True], ids=["in_memory", "spilled"])
+def test_group_merge_spans_say_whether_keys_are_typed(
+    parquet_path, monkeypatch, columns, typed, spill
+):
+    """Integer keys merge as typed arrays, string keys as objects: every
+    `group_merge` span (per batch and the finish) says which."""
+    if spill:
+        monkeypatch.setenv("DEEQU_TPU_MAX_GROUPS_IN_MEMORY", "3")
+    data = Table.scan_parquet(parquet_path, batch_rows=ROWS // 3)
+    with observe.tracing() as tracer:
+        compute_frequencies(data, columns)
+    merges = [s for s in _walk(tracer.roots) if s.name == "group_merge"]
+    assert len(merges) == 4
+    assert all(s.attrs["typed"] is typed for s in merges)
+    assert merges[-1].attrs["spilled"] is spill
+
+
 def test_untraced_sites_open_no_span(parquet_path, on_device, monkeypatch):
     _verify(parquet_path)
     made = []
