@@ -19,6 +19,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from deequ_tpu import observe
 from deequ_tpu.core.exceptions import (
     EmptyStateException,
     NoColumnsSpecifiedException,
@@ -188,12 +189,21 @@ class Analyzer:
         aggregate_with: Optional["StateLoader"] = None,
         save_states_with: Optional["StatePersister"] = None,
     ) -> Metric:
+        # the state layer: `state_load` (read and deserialize),
+        # `state_merge`, `state_save` (serialize and write); no span opens
+        # without a loader or a persister
         if aggregate_with is not None:
-            loaded = aggregate_with.load(self)
+            with observe.span("state_load", cat="state", analyzer=self.name):
+                loaded = aggregate_with.load(self)
             if loaded is not None:
-                state = loaded if state is None else loaded.merge(state)
+                if state is None:
+                    state = loaded
+                else:
+                    with observe.span("state_merge", cat="state", analyzer=self.name):
+                        state = loaded.merge(state)
         if save_states_with is not None and state is not None:
-            save_states_with.persist(self, state)
+            with observe.span("state_save", cat="state", analyzer=self.name):
+                save_states_with.persist(self, state)
         return self.compute_metric_from(state)
 
     def aggregate_state_to(

@@ -29,6 +29,7 @@ from typing import TYPE_CHECKING, Dict, Optional
 import numpy as np
 
 from deequ_tpu.analyzers.states import State
+from deequ_tpu.observe import counters
 
 if TYPE_CHECKING:
     from deequ_tpu.analyzers.base import Analyzer
@@ -183,9 +184,12 @@ class FileSystemStateProvider(StateLoader, StatePersister):
         if isinstance(analyzer, (FrequencyBasedAnalyzer, Histogram)):
             # keep the reference's 3-file on-disk layout
             # (parquet + numRows + columns)
-            self._persist_frequencies(identifier, state)
+            written = self._persist_frequencies(identifier, state)
         else:
-            self._write(identifier, serialize_state(analyzer, state))
+            payload = serialize_state(analyzer, state)
+            self._write(identifier, payload)
+            written = len(payload)
+        counters.record_state_io(saved=1, bytes_saved=written)
 
     # -- load ----------------------------------------------------------------
 
@@ -199,6 +203,7 @@ class FileSystemStateProvider(StateLoader, StatePersister):
         data = self._read(identifier)
         if data is None:
             return None
+        counters.record_state_io(loaded=1, bytes_loaded=len(data))
         return deserialize_state(analyzer, data)
 
     # -- io ------------------------------------------------------------------
@@ -215,9 +220,9 @@ class FileSystemStateProvider(StateLoader, StatePersister):
             return None
         return self.filesystem.read_bytes(path)
 
-    def _persist_frequencies(self, identifier: str, state) -> None:
+    def _persist_frequencies(self, identifier: str, state) -> int:
         """Frequencies as Parquet + numRows binary
-        (reference: StateProvider.scala:211-223)."""
+        (reference: StateProvider.scala:211-223); the bytes written."""
         import pyarrow as pa
         import pyarrow.parquet as pq
 
@@ -237,12 +242,10 @@ class FileSystemStateProvider(StateLoader, StatePersister):
         # write siblings first, parquet last with atomic publish: load()
         # keys on the .pqt, so a crash mid-persist leaves a state that
         # reads as absent, never corrupt
-        self.filesystem.write_bytes(
-            paths["-num_rows.bin"], struct.pack(">q", state.num_rows)
-        )
-        self.filesystem.write_bytes(
-            paths["-columns.txt"], "\n".join(state.columns).encode("utf-8")
-        )
+        num_rows = struct.pack(">q", state.num_rows)
+        columns = "\n".join(state.columns).encode("utf-8")
+        self.filesystem.write_bytes(paths["-num_rows.bin"], num_rows)
+        self.filesystem.write_bytes(paths["-columns.txt"], columns)
         with self.filesystem.open_write(paths["-frequencies.pqt"]) as sink:
             if getattr(state, "is_spilled", False):
                 # disk-spilled state streams partition by partition into
@@ -268,6 +271,7 @@ class FileSystemStateProvider(StateLoader, StatePersister):
                     writer.close()
             else:
                 pq.write_table(pa.table(_frequencies_to_columns(state)), sink)
+            return len(num_rows) + len(columns) + sink.tell()
 
     def _load_frequencies(self, identifier: str):
         import pyarrow.parquet as pq
@@ -277,11 +281,12 @@ class FileSystemStateProvider(StateLoader, StatePersister):
             return None
         columns_payload = self.filesystem.read_bytes(
             self._path(identifier, "-columns.txt")
-        ).decode("utf-8")
-        columns = [line for line in columns_payload.split("\n") if line]
-        (num_rows,) = struct.unpack(
-            ">q", self.filesystem.read_bytes(self._path(identifier, "-num_rows.bin"))
         )
+        columns = [line for line in columns_payload.decode("utf-8").split("\n") if line]
+        num_rows_payload = self.filesystem.read_bytes(
+            self._path(identifier, "-num_rows.bin")
+        )
+        (num_rows,) = struct.unpack(">q", num_rows_payload)
         # load row group by row group through the group-cap accumulator:
         # a persisted high-cardinality state comes back SPILLED, keeping
         # the persist/load round trip bounded-memory on both halves
@@ -296,6 +301,11 @@ class FileSystemStateProvider(StateLoader, StatePersister):
                     pf.read_row_group(g), columns, 0
                 )
                 acc.add(partial)
+            parquet_bytes = source.seek(0, 2)
+        counters.record_state_io(
+            loaded=1,
+            bytes_loaded=len(columns_payload) + len(num_rows_payload) + parquet_bytes,
+        )
         state = acc.finalize()
         state.num_rows = int(num_rows)
         return state

@@ -4,8 +4,10 @@ sinks and active tracers.
 `ops/runtime.py`'s `monitored()` / `record_pass()` / `record_launch()`
 delegate here (source-compatible migration, ISSUE 3 tentpole). A sink
 is any object with `device_passes` / `device_launches` / `group_passes`
-ints, a `pass_labels` list, a `kernel_traces` dict and the placement
-counts `placed_rows` / `device_rows` —
+ints, a `pass_labels` list, a `kernel_traces` dict, the placement
+counts `placed_rows` / `device_rows` and the state-I/O counts
+`states_loaded` / `states_saved` / `state_bytes_loaded` /
+`state_bytes_saved` —
 `runtime.ExecutionStats` in practice, duck-typed so this module never
 imports the ops layer.
 
@@ -97,6 +99,29 @@ def record_group_pass(label: str) -> None:
     tracer = spans.current_tracer()
     if tracer is not None:
         tracer.count("group_passes", 1)
+
+
+def record_state_io(
+    loaded: int = 0, saved: int = 0, bytes_loaded: int = 0, bytes_saved: int = 0
+) -> None:
+    """State-provider I/O (analyzers/state_provider.py): states read back
+    and written, and their serialized bytes each way. The bytes also land
+    as `bytes` on the innermost open span, which is the `state_load` or
+    `state_save` span when `Analyzer.calculate_metric` called the
+    provider."""
+    for sink in _sinks():
+        sink.states_loaded += loaded
+        sink.states_saved += saved
+        sink.state_bytes_loaded += bytes_loaded
+        sink.state_bytes_saved += bytes_saved
+    tracer = spans.current_tracer()
+    if tracer is not None:
+        for name, n in (("states_loaded", loaded), ("states_saved", saved),
+                        ("state_bytes_loaded", bytes_loaded),
+                        ("state_bytes_saved", bytes_saved)):
+            if n:
+                tracer.count(name, n)
+        spans.annotate(bytes=bytes_loaded + bytes_saved)
 
 
 def record_pruned_groups(skipped: int, total: int) -> None:

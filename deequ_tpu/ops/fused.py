@@ -95,11 +95,19 @@ def plan_shape_key(
     share one jitted fused fn, so the jit/fuse cost is paid once per
     shape fleet-wide."""
     return (
-        tuple(repr(a) for a in analyzers),
-        tuple(repr(a) for a in assisted),
+        tuple(_traced_repr(a) for a in analyzers),
+        tuple(_traced_repr(a) for a in assisted),
         layout,
         bool(jax.config.jax_enable_x64),
     )
+
+
+def _traced_repr(a: ScanShareableAnalyzer) -> str:
+    """An analyzer's repr, plus the sample size a quantile family traces
+    into its program (`_sample_size` / `_cap` follow the KLL sizing, not
+    the analyzer's fields)."""
+    size = getattr(a, "_sample_size", None) or getattr(a, "_cap", None)
+    return repr(a) if size is None else f"{a!r}/{size()}"
 
 
 def get_fused_fn(

@@ -825,6 +825,11 @@ class ExecutionStats:
     # mesh-sharded inputs: rows placed (global) and rows each device holds
     placed_rows: int = 0
     device_rows: Dict[str, int] = field(default_factory=dict)
+    # state-provider I/O: states read back and written, bytes each way
+    states_loaded: int = 0
+    states_saved: int = 0
+    state_bytes_loaded: int = 0
+    state_bytes_saved: int = 0
 
     @property
     def jobs(self) -> int:

@@ -7,8 +7,17 @@ updates are batched sorts/decimations over dense arrays (vectorized, no
 per-item pointer chasing) and merge is concatenate+compact, so per-batch
 partial sketches stream from device-filtered values and fold on the host.
 
-Rank error: eps ~ 2.3/k with the default k chosen for the reference's
-relativeError=0.01 contract (reference: analyzers/ApproxQuantile.scala:49).
+Rank error: 2.3/k is KLL's usual 99%-confidence figure for ONE query.
+The reference's relativeError (reference: analyzers/ApproxQuantile.scala:49)
+is a bound that every answer keeps, including those of an incremental
+deployment that merges a day-sized partial into its stored sketch and
+asks again, day after day. So `k_for_error` sizes k at 3.45/eps, 1.5
+times that figure, and compaction is lazy (below): merging a partial into
+a deep sketch costs one compaction where the sketch overflows, not a
+cascade through every level whose depth-scaled capacity it exceeds. Over
+2 x 64 seeds x 256-day chains of 25.5k-row partials the worst rank error
+reads 0.0047 at eps 0.01 (lazy at 2.3/eps: 0.0076; eager at 2.3/eps:
+0.0115).
 Quantile answers pick the smallest item whose cumulative weight reaches
 q*n, matching percentile-of-dataset-element semantics (exact below k items,
 like the reference's digest on small data).
@@ -20,13 +29,13 @@ from typing import List, Optional
 
 import numpy as np
 
-DEFAULT_K = 512  # eps ≈ 2.3/k ≈ 0.0045 < 0.01 default contract
+DEFAULT_K = 512  # eps ≈ 3.45/k ≈ 0.0067 < 0.01 default contract
 
 
 def k_for_error(relative_error: float) -> int:
     if relative_error <= 0:
         return 1 << 16
-    return max(8, int(np.ceil(2.3 / relative_error)))
+    return max(8, int(np.ceil(3.45 / relative_error)))
 
 
 class KLLSketch:
@@ -112,25 +121,42 @@ class KLLSketch:
         return max(8, int(np.ceil(self.k * (c ** (depth - 1 - level)))))
 
     def _compress(self) -> None:
-        level = 0
-        while level < len(self.levels):
-            if len(self.levels[level]) > self._capacity(level):
-                buf = self.levels[level]
-                if len(buf) % 2 == 1:
-                    # hold one item back to keep pairs aligned
-                    keep, buf = buf[:1], buf[1:]
-                else:
-                    keep = np.empty(0, dtype=np.float64)
-                offset = int(self._rng.integers(0, 2))
-                promoted = buf[offset::2]
-                if level + 1 >= len(self.levels):
-                    self.levels.append(np.empty(0, dtype=np.float64))
-                self.levels[level + 1] = np.sort(
-                    np.concatenate([self.levels[level + 1], promoted]),
-                    kind="stable",  # two sorted runs: linear merge
-                )
-                self.levels[level] = keep
-            level += 1
+        """Lazy compaction: nothing happens while the sketch holds no more
+        items than the capacities of its levels add up to; past that, the
+        lowest level at or over its own capacity is compacted, one at a
+        time. Levels may run over their own capacity while others have
+        room, so a small partial merged into a deep sketch is not pushed
+        through every level whose depth-scaled capacity it exceeds. The
+        sketch's levels start at its lowest non-empty one: a sample
+        inserted at level L leaves levels below L empty for good, and
+        counting their room would let a small k hold several times k
+        items (k=23, a sample at level 9: 106)."""
+        while True:
+            low = next((h for h, b in enumerate(self.levels) if len(b)), 0)
+            levels = range(low, len(self.levels))
+            if sum(len(self.levels[h]) for h in levels) <= sum(
+                self._capacity(h) for h in levels
+            ):
+                return
+            # some level is over its capacity when the total is
+            level = next(
+                h for h in levels if len(self.levels[h]) >= self._capacity(h)
+            )
+            buf = self.levels[level]
+            if len(buf) % 2 == 1:
+                # hold one item back to keep pairs aligned
+                keep, buf = buf[:1], buf[1:]
+            else:
+                keep = np.empty(0, dtype=np.float64)
+            offset = int(self._rng.integers(0, 2))
+            promoted = buf[offset::2]
+            if level + 1 >= len(self.levels):
+                self.levels.append(np.empty(0, dtype=np.float64))
+            self.levels[level + 1] = np.sort(
+                np.concatenate([self.levels[level + 1], promoted]),
+                kind="stable",  # two sorted runs: linear merge
+            )
+            self.levels[level] = keep
 
     # -- merge ---------------------------------------------------------------
 

@@ -383,7 +383,7 @@ class AnalysisRunner:
 
         aggregated = InMemoryStateProvider()
         with observe.span(
-            "state_merge", cat="merge",
+            "state_merge", cat="state",
             analyzers=len(passed), loaders=len(state_loaders),
         ):
             for analyzer in passed:
