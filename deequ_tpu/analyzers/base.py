@@ -309,9 +309,19 @@ def where_spec(where: Optional[str]) -> InputSpec:
     pred = Predicate(where)
     return InputSpec(
         key=where_key(where),
-        build=lambda t: pred.eval_mask(t),
+        build=lambda t: predicate_input(pred, t),
         columns=tuple(sorted(set(pred.referenced_columns()))),
     )
+
+
+def predicate_input(pred: Predicate, t: Table, nonnull: bool = False) -> np.ndarray:
+    """One predicate input build, counted by route: the row mask (NULL ->
+    False), or with `nonnull` the mask of rows whose result is not NULL."""
+    (v, null, _), route, entries = pred.eval_routed(t)
+    observe.counters.record_predicate_eval(route, t.num_rows, entries)
+    if nonnull:
+        return ~null
+    return np.asarray(v, dtype=bool) & ~null
 
 
 class ScanShareableAnalyzer(Analyzer):

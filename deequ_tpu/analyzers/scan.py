@@ -27,6 +27,7 @@ from deequ_tpu.analyzers.base import (
     ScanShareableAnalyzer,
     col_valid_spec,
     col_values_spec,
+    predicate_input,
     render_where,
     where_key,
     where_spec,
@@ -252,7 +253,7 @@ def _pred_spec(predicate: str) -> InputSpec:
     pred = Predicate(predicate)
     return InputSpec(
         key=f"pred:{predicate}",
-        build=lambda t: pred.eval_mask(t),
+        build=lambda t: predicate_input(pred, t),
         columns=tuple(sorted(set(pred.referenced_columns()))),
     )
 
@@ -261,14 +262,9 @@ def _pred_nonnull_spec(predicate: str) -> InputSpec:
     from deequ_tpu.data.expr import Predicate
 
     pred = Predicate(predicate)
-
-    def build(t: Table) -> np.ndarray:
-        _, null, _ = pred.eval(t)
-        return ~null
-
     return InputSpec(
         key=f"prednn:{predicate}",
-        build=build,
+        build=lambda t: predicate_input(pred, t, nonnull=True),
         columns=tuple(sorted(set(pred.referenced_columns()))),
     )
 

@@ -21,7 +21,13 @@ from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from deequ_tpu.data.table import Column, ColumnType, Table
+from deequ_tpu.data.table import (
+    Column,
+    ColumnType,
+    Table,
+    cached_dictionary_encode,
+    root_column,
+)
 
 
 class ExpressionParseError(ValueError):
@@ -680,20 +686,89 @@ def _eval_func(node: Func, table: Table, n: int) -> Series:
 # ---------------------------------------------------------------------------
 
 
+def _dictionary_column(table: Table, name: str) -> Optional[Column]:
+    """The column `name` when a predicate over it alone can be evaluated
+    once per dictionary entry: a STRING column that already carries
+    dictionary codes (the `dict_encode` memo on its root, set by both
+    Parquet dictionary decode routes or by any consumer that encoded it)
+    over fewer entries than the batch has rows. None keeps the row path."""
+    if not table.has_column(name):
+        return None
+    col = table.column(name)
+    if col.ctype != ColumnType.STRING:
+        return None
+    memo = root_column(col)._cache.get("dict_encode")
+    # object uniques only: the numpy encode fallback stringifies mixed
+    # backing values, so its entries need not equal the rows' own values
+    if memo is None or memo[1].dtype != object or len(memo[1]) >= table.num_rows:
+        return None
+    return col
+
+
 class Predicate:
-    """A parsed SQL-ish expression evaluable over a Table."""
+    """A parsed SQL-ish expression evaluable over a Table.
+
+    A predicate over one dictionary-coded STRING column (see
+    `_dictionary_column`) is evaluated once over the dictionary's entries
+    plus a null slot and gathered to rows through the codes: every
+    construct of `_eval` is row-wise and deterministic, and a null row
+    reads as the null slot ("" with valid False, the neutral fill the
+    row path sees), so the result is bit-identical to the row path's.
+    The per-entry result is memoized on the dictionary
+    (`cached_dictionary_encode`), shared by every input build of the
+    batch and by later batches whose dictionaries are equal."""
 
     def __init__(self, expression: str):
         self.expression = expression
         self.ast = parse(expression)
+        names = set(self.referenced_columns())
+        self._single = names.pop() if len(names) == 1 else None
 
     def eval_mask(self, table: Table) -> np.ndarray:
         """Boolean row mask; NULL -> False (SQL WHERE semantics)."""
-        v, null, kind = _eval(self.ast, table, table.num_rows)
+        v, null, kind = self.eval(table)
         return np.asarray(v, dtype=bool) & ~null
 
     def eval(self, table: Table) -> Series:
-        return _eval(self.ast, table, table.num_rows)
+        return self.eval_routed(table)[0]
+
+    def eval_routed(self, table: Table) -> Tuple[Series, str, int]:
+        """(series, route, entries): route is "dictionary" or "rows";
+        entries counts the dictionary entries evaluated here (0 on the
+        row path and on a memo hit)."""
+        col = (
+            _dictionary_column(table, self._single)
+            if self._single is not None
+            else None
+        )
+        if col is None:
+            return _eval(self.ast, table, table.num_rows), "rows", 0
+        evaluated = []
+
+        def per_entry(root: Column) -> Series:
+            uniques = root.dict_encode()[1]
+            n = len(uniques) + 1
+            values = np.empty(n, dtype=object)
+            values[:-1] = uniques
+            values[-1] = ""
+            valid = np.ones(n, dtype=bool)
+            valid[-1] = False
+            entries = Table([Column(root.name, ColumnType.STRING, values, valid)])
+            evaluated.append(len(uniques))
+            return _eval(self.ast, entries, n)
+
+        try:
+            ev, en, kind = cached_dictionary_encode(
+                col, f"pred:{self.expression}", per_entry
+            )
+        except (ArithmeticError, ValueError):
+            # an entry this batch does not use may raise (a string cast
+            # of ABS('inf')): whether the batch raises, and with which
+            # error, is the row path's answer
+            return _eval(self.ast, table, table.num_rows), "rows", 0
+        # dict_encode's null code -1 indexes the trailing null slot
+        codes = col.dict_encode()[0]
+        return (ev[codes], en[codes], kind), "dictionary", sum(evaluated)
 
     def referenced_columns(self) -> List[str]:
         out: List[str] = []

@@ -278,6 +278,14 @@ def _derived_nbytes(value) -> int:
     return 64  # scalars / small objects: nominal
 
 
+def root_column(col: "Column") -> "Column":
+    """The column a chain of `Column.slice`s was cut from: the holder of
+    the memos its slices share."""
+    while getattr(col, "_parent", None) is not None:
+        col = col._parent[0]
+    return col
+
+
 def cached_dictionary_encode(col: "Column", key: str, compute):
     """DICTIONARY-level derived value (classify / numeric parse / hash of
     the dictionary itself — NOT row data): memoized on the root Column
@@ -288,9 +296,7 @@ def cached_dictionary_encode(col: "Column", key: str, compute):
     thousand strings. The cross-batch tier is bounded by entry count AND
     bytes (LRU eviction)."""
     global _DICT_DERIVED_CACHE, _DICT_DERIVED_BYTES
-    root = col
-    while getattr(root, "_parent", None) is not None:
-        root = root._parent[0]
+    root = root_column(col)
     cached = root._cache.get(key)
     if cached is not None:
         return cached

@@ -5,9 +5,10 @@ sinks and active tracers.
 delegate here (source-compatible migration, ISSUE 3 tentpole). A sink
 is any object with `device_passes` / `device_launches` / `group_passes`
 ints, a `pass_labels` list, a `kernel_traces` dict, the placement
-counts `placed_rows` / `device_rows` and the state-I/O counts
+counts `placed_rows` / `device_rows`, the state-I/O counts
 `states_loaded` / `states_saved` / `state_bytes_loaded` /
-`state_bytes_saved` —
+`state_bytes_saved` and the predicate-build counts
+`pred_builds_dictionary` / `pred_builds_rows` / `pred_dict_entries` —
 `runtime.ExecutionStats` in practice, duck-typed so this module never
 imports the ops layer.
 
@@ -122,6 +123,28 @@ def record_state_io(
             if n:
                 tracer.count(name, n)
         spans.annotate(bytes=bytes_loaded + bytes_saved)
+
+
+def record_predicate_eval(route: str, rows: int, entries: int) -> None:
+    """One predicate input build (a `pred:` / `prednn:` / `where:` mask)
+    over `rows` rows, on `route` "dictionary" (evaluated once per entry
+    of a dictionary-coded string column, gathered through the codes) or
+    "rows"; `entries` is the dictionary entries evaluated, 0 on a memo
+    hit. The route also lands as `route` on the innermost open span,
+    which is the `build` span when `HostInputs` built the mask."""
+    dictionary = route == "dictionary"
+    for sink in _sinks():
+        if dictionary:
+            sink.pred_builds_dictionary += 1
+        else:
+            sink.pred_builds_rows += 1
+        sink.pred_dict_entries += entries
+    tracer = spans.current_tracer()
+    if tracer is not None:
+        tracer.count(f"pred_builds_{route}", 1)
+        if entries:
+            tracer.count("pred_dict_entries", int(entries))
+        spans.annotate(route=route)
 
 
 def record_pruned_groups(skipped: int, total: int) -> None:

@@ -724,11 +724,15 @@ def plan_row_group_prune(table, members):
 
 #: spec-key prefixes whose builds consume only the packed representation
 #: of a dictionary-string column (codes + mask + uniques digest) — the
-#: lazy per-row string gather provably never fires, so such columns are
-#: safe for the native decode's lazy-values Column. An unknown prefix
-#: routes the column to the host chain instead (conservative, never
-#: wrong). Numeric/bool columns skip this check: their Columns are fully
-#: materialized by both paths.
+#: lazy per-row string gather never fires, so such columns are safe for
+#: the native decode's lazy-values Column. The predicate prefixes
+#: (`where`, `pred`, `prednn`) hold this for a predicate over that one
+#: column, which `data/expr.py:Predicate` evaluates per dictionary entry;
+#: one over several columns, or over a dictionary about as large as the
+#: batch, takes the row path and materializes the strings on demand
+#: (slower, never wrong). An unknown prefix routes the column to the host
+#: chain instead (conservative, never wrong). Numeric/bool columns skip
+#: this check: their Columns are fully materialized by both paths.
 PACKED_SAFE_PREFIXES = frozenset(
     {
         "num", "valid", "where", "pred", "prednn", "match", "dtclass",

@@ -830,6 +830,11 @@ class ExecutionStats:
     states_saved: int = 0
     state_bytes_loaded: int = 0
     state_bytes_saved: int = 0
+    # predicate input builds by route (dictionary entries vs rows), and
+    # the dictionary entries the dictionary route evaluated
+    pred_builds_dictionary: int = 0
+    pred_builds_rows: int = 0
+    pred_dict_entries: int = 0
 
     @property
     def jobs(self) -> int:
