@@ -1,6 +1,6 @@
 PY ?= python
 
-.PHONY: lint typecheck analyze sentinel test test-fast trace-demo chaos service-chaos bench-pushdown bench-decode bench-wire bench-incremental bench-reader bench-encfold bench-forensics bench-chaos bench-service bench-mesh bench-sharing bench-window clean-native
+.PHONY: lint typecheck sentinel test test-fast trace-demo chaos service-chaos clean-native
 
 lint:
 	$(PY) tools/lint.py
@@ -16,84 +16,14 @@ typecheck:
 		echo "typecheck: mypy not installed — skipping (pip install mypy to enable)"; \
 	fi
 
-# the full static-analysis suite: repo lints, types, and a smoke
-# EXPLAIN over the benchmark plan (proves the cost analyzer runs
-# end-to-end without touching data)
-analyze: lint typecheck
-	JAX_PLATFORMS=cpu $(PY) tools/explain_bench.py
-
 # regression sentinel: anomaly strategies over the engine telemetry
-# series (ENGINE_METRICS.json, appended by bench runs) and the
-# committed BENCH_r0*.json history; exits nonzero when throughput or
-# phase shares regress — see BENCH.md
+# series (ENGINE_METRICS.json) and the committed BENCH_r0*.json
+# history; exits nonzero when throughput or phase shares regress
 sentinel:
 	JAX_PLATFORMS=cpu $(PY) tools/sentinel.py
 
 trace-demo:
 	JAX_PLATFORMS=cpu PYTHONPATH=.:examples $(PY) examples/tracing_example.py
-
-# row-group pushdown A/B over a sorted-key parquet file: same
-# where-heavy plan with DEEQU_TPU_PUSHDOWN=0 then =1, bit-identity
-# asserted, skipped-group counts from the traced pass. Refreshes
-# BENCH_PUSHDOWN.json (methodology: BENCH.md round 8)
-bench-pushdown:
-	JAX_PLATFORMS=cpu BENCH_MODE=pushdown $(PY) bench.py
-
-# decode fast-path A/B over the 50-column wide stream shape: same
-# decode-bound plan with DEEQU_TPU_DECODE_FASTPATH=0 then =1 (plus a
-# worker-pool pass), bit-identity asserted, decode self-seconds from
-# traced passes. Refreshes BENCH_DECODE.json (methodology: BENCH.md
-# round 9)
-BENCH_DECODE_ROWS ?= 4000000
-bench-decode:
-	JAX_PLATFORMS=cpu BENCH_MODE=decode BENCH_ROWS=$(BENCH_DECODE_ROWS) $(PY) bench.py
-
-# decode-to-wire fusion A/B over the same 50-column wide stream shape:
-# same packed-wire-safe plan with DEEQU_TPU_WIRE_FUSED=0 then =1,
-# bit-identity asserted, decode+prep combined self-seconds from traced
-# warm passes plus warm-jit cold-IO wall times. Refreshes
-# BENCH_WIRE.json (methodology: BENCH.md round 10)
-BENCH_WIRE_ROWS ?= 4000000
-bench-wire:
-	JAX_PLATFORMS=cpu BENCH_MODE=wire BENCH_ROWS=$(BENCH_WIRE_ROWS) $(PY) bench.py
-
-# persistent partition-state cache A/B: cold full scan fills the
-# repository, one partition is appended, then a cache-off full rescan
-# races the warm incremental pass (cached loads + 1 scanned partition).
-# Aborts unless metrics are bit-identical and the trace pins exactly one
-# partition scanned. Refreshes BENCH_INCREMENTAL.json (methodology:
-# BENCH.md round 11)
-BENCH_INCREMENTAL_ROWS ?= 6000000
-bench-incremental:
-	JAX_PLATFORMS=cpu BENCH_MODE=incremental BENCH_ROWS=$(BENCH_INCREMENTAL_ROWS) $(PY) bench.py
-
-# native parquet reader A/B over the cold 50-column stream shape under
-# the 50ms object-store stall model: same plan with
-# DEEQU_TPU_NATIVE_READER=0 then =1, bit-identity asserted, decode-stage
-# self-seconds from traced passes plus untraced cold-IO wall times.
-# Refreshes BENCH_READER.json (methodology: BENCH.md round 12)
-BENCH_READER_ROWS ?= 4000000
-bench-reader:
-	JAX_PLATFORMS=cpu BENCH_MODE=reader BENCH_ROWS=$(BENCH_READER_ROWS) $(PY) bench.py
-
-# encoded-data fold A/B on the low-cardinality half of the 50-column
-# wide-stream shape: same plan with DEEQU_TPU_ENCODED_FOLD=0 (row-width
-# expansion) then =1 (run/dictionary folding), native reader on both
-# sides, bit-identity asserted — the bench ABORTS on any metric
-# mismatch or plan/runtime drift. Refreshes BENCH_ENCFOLD.json
-# (methodology: BENCH.md round 20)
-BENCH_ENCFOLD_ROWS ?= 4000000
-bench-encfold:
-	JAX_PLATFORMS=cpu BENCH_MODE=encfold BENCH_ROWS=$(BENCH_ENCFOLD_ROWS) $(PY) bench.py
-
-# failure-forensics capture A/B on the wide-stream shape: the same
-# verification run with .with_forensics() off then on, bit-identity
-# asserted; a completeness constraint failing ~3% of rows makes every
-# batch capture-heavy. Refreshes BENCH_FORENSICS.json (methodology:
-# BENCH.md round 13)
-BENCH_FORENSICS_ROWS ?= 2000000
-bench-forensics:
-	JAX_PLATFORMS=cpu BENCH_MODE=forensics BENCH_ROWS=$(BENCH_FORENSICS_ROWS) $(PY) bench.py
 
 # seeded fault matrix (ISSUE 13): the chaos harness's injection
 # schedule determinism + retry/cancel/watchdog semantics, the chaos
@@ -114,55 +44,6 @@ service-chaos:
 	JAX_PLATFORMS=cpu $(PY) -m pytest tests/test_service_chaos.py -q
 	JAX_PLATFORMS=cpu $(PY) -m pytest tests/test_service.py -q
 	JAX_PLATFORMS=cpu $(PY) -m pytest tests/test_pipeline_shutdown.py -q -k "service"
-
-# service scheduling benchmark (ISSUE 14): interactive p99 latency on a
-# single-worker service while a heavy partitioned profile holds the
-# pool — must stay within 2x of solo p99 because every interactive
-# arrival preempts the heavy run at a partition boundary and the heavy
-# run completes from committed states. Refreshes BENCH_SERVICE.json
-BENCH_SERVICE_ROWS ?= 2000000
-bench-service:
-	JAX_PLATFORMS=cpu BENCH_SERVICE_ROWS=$(BENCH_SERVICE_ROWS) $(PY) tools/bench_service.py
-
-# resilience-machinery A/B on the wide-stream shape: the same
-# verification run plain vs armed (RunController + every fault point
-# deciding at rate 0), bit-identity asserted, plus one seeded fault
-# pass that must land bit-identical. Proves <2% clean-path overhead.
-# Refreshes BENCH_CHAOS.json (methodology: BENCH.md round 14)
-BENCH_CHAOS_ROWS ?= 2000000
-bench-chaos:
-	JAX_PLATFORMS=cpu BENCH_MODE=chaos BENCH_ROWS=$(BENCH_CHAOS_ROWS) $(PY) bench.py
-
-# sharded streaming scan scaling curve (ISSUE 15): the IO-latency-bound
-# cold pass at 1/2/4 REAL processes, rendezvous partition sharding,
-# states-only allgather. Must reach >=3x wall at 4 processes with
-# per-process scan throughput within 15% of solo, and every mesh size
-# must report metrics bit-identical to the solo pass. Refreshes
-# BENCH_MESH.json (methodology: BENCH.md round 15)
-BENCH_MESH_ROWS ?= 128000
-bench-mesh:
-	JAX_PLATFORMS=cpu BENCH_MESH_ROWS=$(BENCH_MESH_ROWS) $(PY) tools/bench_mesh.py
-
-# fleet-wide scan-sharing benchmark (ISSUE 17): 4 co-tenant suites
-# grouped onto ONE proven union scan must finish in <=1.5x a single
-# scan's wall time (vs ~4x independent), with every participant
-# bit-identical to its solo run and every CONTAINED proof pinned at
-# zero drift — the bench ABORTS on any mismatch. Refreshes
-# BENCH_SHARING.json (methodology: BENCH.md round 17)
-BENCH_SHARING_ROWS ?= 8000000
-bench-sharing:
-	JAX_PLATFORMS=cpu BENCH_SHARING_ROWS=$(BENCH_SHARING_ROWS) $(PY) tools/bench_sharing.py
-
-# windowed state algebra A/B (ISSUE 18): a 30-partition daily dataset
-# is cold-filled, then a warm 7-day sliding window query plus a
-# week-over-week drift check — pure DQSG segment merges, zero data rows
-# — races cache-off full rescans of the same current+prior week
-# partitions. A traced proof pass pins partitions_scanned == 0 and
-# every cover span a segment hit; any metric mismatch ABORTS. Refreshes
-# BENCH_WINDOW.json (methodology: BENCH.md round 18)
-BENCH_WINDOW_ROWS ?= 6000000
-bench-window:
-	JAX_PLATFORMS=cpu BENCH_MODE=window BENCH_ROWS=$(BENCH_WINDOW_ROWS) $(PY) bench.py
 
 # remove cached native builds (the hash-named .so files): any strays in
 # the package tree from older versions plus the per-user cache dir the

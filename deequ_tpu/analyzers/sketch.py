@@ -338,7 +338,7 @@ import zlib
 def _batch_seed(sample: np.ndarray, n: int, level: int) -> int:
     """Deterministic per-batch sketch seed: KLL's error bound wants
     compaction offsets that decorrelate across merged partials, and the
-    engine's differential contracts (pipeline on/off, engine parity,
+    engine's differential contracts (streamed vs in-memory, engine parity,
     repeated runs in one process) need bit-identical results. Hashing
     the batch's own decimated sample gives both — distinct batches get
     distinct offsets, while a scan's outcome depends only on its inputs

@@ -88,8 +88,7 @@ def _decode_table(arrow_table, fastpath, wire=None) -> Table:
 
     The span isolates the buffer->wire conversion self-time from the
     parquet read/decompression that surrounds it in the decode stage,
-    so traces (and BENCH_DECODE.json) report the exact seconds the
-    decode fast path targets. `wire_fuse` counts the columns this batch
+    so traces report the exact seconds the decode fast path targets. `wire_fuse` counts the columns this batch
     decoded straight to wire buffers (decode-to-wire fusion)."""
     sp = _spans.span("arrow_decode", cat="decode")
     with sp:
@@ -179,18 +178,7 @@ class DataSource:
         the thread within JOIN_TIMEOUT_S. The producer closes its
         `_iter_tables` iterator ON the producer thread before exiting,
         so file handles (e.g. the open ParquetFile) release
-        deterministically rather than at garbage collection.
-
-        `DEEQU_TPU_PIPELINE=0` (runtime.pipeline_enabled) decodes
-        synchronously on the caller's thread instead — no prefetch
-        thread, no queue: the fully SERIAL fallback the stream
-        pipeline's differential tests compare against. Batch content
-        and order are identical either way."""
-        from deequ_tpu.ops import runtime
-
-        if not runtime.pipeline_enabled():
-            yield from self._batches_serial(batch_size)
-            return
+        deterministically rather than at garbage collection."""
         q: "queue.Queue" = queue.Queue(maxsize=2)
         stop = threading.Event()
         error: List[BaseException] = []
@@ -281,22 +269,6 @@ class DataSource:
             # zero-row source: one empty batch so aggregations see the
             # schema and produce their empty-state verdicts, matching the
             # in-memory Table contract
-            yield Table([_empty_column(n, t) for n, t in self._schema()])
-
-    def _batches_serial(self, batch_size: int) -> Iterator[Table]:
-        """The DEEQU_TPU_PIPELINE=0 decode: same iterator, same batch
-        sequence, same empty-batch fallback — on the calling thread."""
-        produced_any = False
-        it = self._iter_tables(batch_size)
-        try:
-            for table in it:
-                produced_any = True
-                yield table
-        finally:
-            close = getattr(it, "close", None)
-            if close is not None:
-                close()
-        if not produced_any:
             yield Table([_empty_column(n, t) for n, t in self._schema()])
 
 
@@ -611,7 +583,8 @@ class ParquetSource(DataSource):
 
     def _decode_fastpath_set(self) -> Optional[frozenset]:
         """The planner-approved fast-decode set, or None when the knob
-        forces the host chain (the decode differential's baseline)."""
+        forces the host chain (the baseline the decode tests compare
+        against; only tests set it)."""
         from deequ_tpu.ops import runtime
 
         if self.decode_fastpath and runtime.decode_fastpath_enabled():
@@ -622,7 +595,7 @@ class ParquetSource(DataSource):
         """The attached WireFusionPlan when the kill switch allows it.
         Wire fusion rides on the native fast path, so both knobs gate
         it — DEEQU_TPU_WIRE_FUSED=0 (or fastpath off) restores the
-        exact pre-fusion decode for the differential baseline."""
+        exact pre-fusion decode, the baseline the tests compare against."""
         from deequ_tpu.ops import runtime
 
         if (
@@ -654,7 +627,8 @@ class ParquetSource(DataSource):
         """The planner-approved encoded-fold spec map restricted to the
         active native-reader columns, or None when the
         DEEQU_TPU_ENCODED_FOLD kill switch (or any native-reader gate)
-        turns the run-fold path off — the differential's baseline."""
+        turns the run-fold path off — the baseline the tests compare
+        against."""
         from deequ_tpu.ops import runtime
 
         if (

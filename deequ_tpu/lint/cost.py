@@ -188,7 +188,7 @@ class PipelineCost:
     single transfer outlasting d batches of host work starves the fold
     stage no matter how the stages interleave (the DQ305 condition)."""
 
-    enabled: bool
+    enabled: bool  # the source is streamed, so the staged driver runs
     queue_depth: int
     stages: Tuple[str, ...] = ("decode", "prep", "fold")
     n_batches: int = 1
@@ -1047,7 +1047,7 @@ def analyze_plan(
                 overlapped = max(host_s, wire_s)
                 bottleneck = "transfer" if wire_s > host_s else "host"
             cost.pipeline = PipelineCost(
-                enabled=runtime.pipeline_enabled(),
+                enabled=streaming,
                 queue_depth=depth,
                 n_batches=batches,
                 wire_bytes_per_batch=wire_exact if use_device else 0,

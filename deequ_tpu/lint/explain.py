@@ -565,7 +565,7 @@ def render_explain(
         body.append("(no passes: nothing to compute)")
     pipe = cost.pipeline
     if pipe is not None:
-        state = "on" if pipe.enabled else "off (DEEQU_TPU_PIPELINE=0)"
+        state = "on" if pipe.enabled else "off"
         body.append(
             f"stream pipeline: {state}   depth: {pipe.queue_depth}   "
             f"stages: {' > '.join(pipe.stages)}"

@@ -77,9 +77,8 @@ def pipeline_occupancy(roots: Roots) -> List[Dict[str, Any]]:
     hide inside another stage's time, so the read row is promoted to
     the bottleneck slot instead of the stall showing up as idle decode.
     Pure function of the spans; the same rows back `render_report`'s
-    pipeline section and the bench artifacts' occupancy breakdown.
-    Empty when the run never engaged the pipeline (serial fallback,
-    in-memory tables)."""
+    pipeline section.
+    Empty when the run never engaged the pipeline (in-memory tables)."""
     rows: Dict[str, Dict[str, Any]] = {}
     order: List[str] = []
     readahead = {"spans": 0, "busy_s": 0.0, "hits": 0, "misses": 0}

@@ -2200,6 +2200,289 @@ class PipelinedAggFold:
         ), self._assisted_states
 
 
+class ChipPlacement:
+    """How a batch's device inputs reach one chip: packed into the wire
+    (the decode workers' pre-packed columns spliced in) and run by the
+    fused program of the pack's layout. The source's WireFusionPlan, when
+    attached, hears every resolved shift so decode workers can fuse
+    shift-needing columns; a device failure abandons that handshake."""
+
+    def __init__(self, analyzers, assisted, keys, batch_size, dtype, sticky, wire_plan):
+        self.analyzers, self.assisted, self.keys = analyzers, assisted, keys
+        self.batch_size, self.dtype, self.sticky = batch_size, dtype, sticky
+        self.wire_plan = wire_plan
+
+    def attrs(self, batch) -> Dict[str, Any]:
+        return {"wire_fuse": len(getattr(batch, "wire_rows", None) or {})}
+
+    def place(self, batch, built):
+        wire_rows = getattr(batch, "wire_rows", None) or {}
+        # the H2D put happens here (jnp.asarray inside pack_batch_inputs)
+        inputs, layout = pack_batch_inputs(
+            [(k, None if k in wire_rows else built[k]) for k in self.keys],
+            _pad_size(batch.num_rows, self.batch_size),
+            self.dtype,
+            self.sticky,
+            num_rows=batch.num_rows,
+            prepacked=wire_rows,
+        )
+        if self.wire_plan is not None:
+            # one thread packs, in batch order: the sticky shifts are
+            # final after this pack, so the decode workers' gate opens
+            self.wire_plan.publish_shifts(
+                {
+                    k: float(self.sticky.get(f"shift:{k}", 0.0))
+                    for k in self.wire_plan.shift_keys
+                }
+            )
+        return inputs, layout
+
+    def launch(self, inputs, layout):
+        """Start the program -> (device outputs, meta box, shard rows)."""
+        fused, meta_box = get_fused_fn(self.analyzers, self.assisted, layout)
+        runtime.record_launch()
+        return fused(inputs), meta_box, None
+
+
+class ScanStep:
+    """One batch of a scan pass, shared by FusedScanPass and
+    DistributedScanPass; only the placement (ChipPlacement, or the
+    mesh's) differs between them.
+
+    `_prep` builds the batch's inputs and places the device ones;
+    `_fold` launches the device program, submits its output to the
+    ordered `PipelinedAggFold`, folds the host members and keeps the
+    counts. `run` drives them: inline on the calling thread for an
+    in-memory table (the program launches before the host fold, and
+    family kernels run inside the fold), staged for a streamed source
+    (`pipeline.staged` runs `_prep` and the family kernels a batch ahead
+    on its own thread). Every fold runs on the calling thread in batch
+    order over the same inputs either way, so the two drivers agree bit
+    for bit. A failed input or program fails only its consumers: the
+    device group as a whole, host members one by one (reference:
+    AnalysisRunner.scala:310-313)."""
+
+    def __init__(
+        self,
+        placement,
+        fold: PipelinedAggFold,
+        specs,
+        device_keys,
+        host_members,
+        host_assisted,
+        host_member_keys,
+        streaming: bool,
+        controller=None,
+        forensics=None,
+        progress=observe.heartbeat.NOOP_PROGRESS,
+    ):
+        self.placement = placement  # None: no device member
+        self.fold = fold
+        self.specs = specs
+        self.device_keys = sorted(device_keys)
+        self.host_members, self.host_assisted = host_members, host_assisted
+        self.all_host = list(host_members) + list(host_assisted)
+        self.host_member_keys = host_member_keys
+        self.streaming = streaming
+        self.controller, self.forensics, self.progress = (
+            controller, forensics, progress
+        )
+        self.host_aggs: Dict[int, Any] = {}
+        self.host_assisted_states: Dict[int, Any] = {}
+        self.host_errors: Dict[int, BaseException] = {}
+        self.family_memo: Dict[Any, Any] = {}  # cross-batch, one scan's scope
+        self.device_error: Optional[BaseException] = None
+        # the prep side's view of device_error: once set, batches still
+        # to prep stop paying for device builds and puts
+        self.device_down = threading.Event()
+        self.rows = 0
+        self.batches = 0
+
+    def run(self, table, batch_size: int) -> None:
+        batches = table.batches(batch_size)
+        if not self.streaming:
+            for batch in batches:
+                if not self._fold(self._prep(batch)):
+                    break
+            return
+        items = pipeline.staged(
+            batches, self._prep, name="prep", progress=self.progress
+        )
+        # an exception here (a cancel, say) unwinds through closing(),
+        # which joins every stage thread and file descriptor
+        with contextlib.closing(items), observe.span(
+            "pipe_stage", cat="pipeline", stage="fold"
+        ) as stage_sp:
+            for item in items:
+                if not self._fold(item):
+                    break
+            if stage_sp:
+                stage_sp.set(items=self.batches)
+
+    def _host_live(self) -> bool:
+        return any(i not in self.host_errors for i, _m in self.all_host)
+
+    def _set_device_down(self) -> None:
+        self.device_down.set()
+        if self.placement.wire_plan is not None:
+            self.placement.wire_plan.abandon_shifts()
+
+    def _prep(self, batch):
+        built = HostInputs(self.specs, batch)
+        placed = device_exc = None
+        if self.placement is not None and not self.device_down.is_set():
+            if self.placement.wire_plan is not None:
+                # opens the decode workers' shift wait window: a publish
+                # is imminent, so overlapped batches wait, not fall back
+                self.placement.wire_plan.mark_pack_started()
+            # device keys build eagerly; host-only keys build lazily on
+            # member access; keys the decode workers already emitted in
+            # wire form (batch.wire_rows) skip the build entirely
+            wire_rows = getattr(batch, "wire_rows", None) or {}
+            for key in self.device_keys:
+                if key not in wire_rows:
+                    built.materialize(key)
+            try:
+                with observe.span(
+                    "dispatch",
+                    cat="dispatch",
+                    rows=batch.num_rows,
+                    **self.placement.attrs(batch),
+                ) as dispatch_sp:
+                    for key in self.device_keys:
+                        if key in built.build_errors:
+                            raise built.build_errors[key]
+                    placed = self.placement.place(batch, built)
+                    if dispatch_sp:
+                        dispatch_sp.set(
+                            wire_bytes=int(
+                                sum(
+                                    int(getattr(v, "nbytes", 0))
+                                    for v in placed[0].values()
+                                )
+                            )
+                        )
+            except Exception as e:  # noqa: BLE001
+                device_exc, placed = e, None
+                self._set_device_down()
+        if self.streaming and self._host_live():
+            with observe.span("host_prep", cat="host", rows=batch.num_rows):
+                _precompute_family_kernels(
+                    built, self.host_assisted, batch,
+                    host_members=self.host_members,
+                    host_errors=self.host_errors,
+                    streaming=True, family_memo=self.family_memo,
+                )
+        return batch, built, placed, device_exc
+
+    def _fold(self, item) -> bool:
+        """Fold one prepped batch; False once every member has failed."""
+        batch, built, placed, device_exc = item
+        ctl = self.controller
+        if ctl is not None:
+            ctl.check(
+                where="fused_scan batch",
+                progress={"batches": self.batches, "rows": self.rows},
+            )
+        device_live = self.placement is not None and self.device_error is None
+        if not device_live and not self._host_live():
+            return False
+        with self._item_scope(batch):
+            if device_live:
+                if device_exc is not None:
+                    self.device_error = device_exc
+                elif placed is not None:
+                    try:
+                        with observe.span("launch", cat="dispatch"):
+                            out, meta_box, shard_rows = self.placement.launch(
+                                *placed
+                            )
+                        # async dispatch; submit folds the PREVIOUS batch
+                        # while the device crunches this one
+                        self.fold.submit(
+                            out, meta_box, host_ctx=built, shard_rows=shard_rows
+                        )
+                    except Exception as e:  # noqa: BLE001
+                        self.device_error = e
+                if self.device_error is not None:
+                    self._set_device_down()
+            with observe.span("host_fold", cat="host", rows=batch.num_rows):
+                fold_host_batch(
+                    built, built.build_errors, self.host_members,
+                    self.host_assisted, self.host_member_keys, self.host_aggs,
+                    self.host_assisted_states, self.host_errors,
+                    batch=batch, streaming=self.streaming,
+                    family_memo=self.family_memo, precomputed=self.streaming,
+                )
+            if self.forensics is not None:
+                with observe.span(
+                    "forensics_capture", cat="forensics", rows=batch.num_rows
+                ):
+                    self.forensics.capture_batch(batch, self.rows)
+        self.rows += batch.num_rows
+        self.batches += 1
+        if ctl is not None:
+            ctl.beat()
+        self.progress.advance(batch.num_rows)
+        return True
+
+    @contextlib.contextmanager
+    def _item_scope(self, batch):
+        if not self.streaming:
+            yield
+            return
+        with self.progress.timed("fold"), observe.span(
+            "pipe_item", cat="pipeline", stage="fold", rows=batch.num_rows
+        ):
+            yield
+
+    def finish(self):
+        """-> (device aggregates, assisted states, host results, device
+        error). The final device_get lives here: an execution or
+        transfer failure surfaces now and must not erase host outcomes."""
+        aggs, assisted_states = [], []
+        device_error = self.device_error
+        if device_error is None:
+            try:
+                aggs, assisted_states = self.fold.finish()
+                shifts = wire_shifts(self.fold.sticky)
+                if shifts:
+                    aggs = [
+                        a.unshift_agg(agg, shifts)
+                        for a, agg in zip(self.fold.analyzers, aggs)
+                    ]
+            except Exception as e:  # noqa: BLE001
+                device_error = e
+        host_results = materialize_host_results(
+            self.host_members, self.host_assisted, self.host_aggs,
+            self.host_assisted_states, self.host_errors,
+        )
+        return aggs, assisted_states, host_results, device_error
+
+
+def device_results(
+    analyzers, merge_idx, assisted_idx, aggs, assisted_states, device_error
+) -> Dict[int, "AnalyzerRunResult"]:
+    """The device members' results from a finished step: a failure of
+    the shared program fails every analyzer in it (reference:
+    AnalysisRunner.scala:310-313)."""
+    results: Dict[int, AnalyzerRunResult] = {}
+    if device_error is not None:
+        for i in list(merge_idx) + list(assisted_idx):
+            results[i] = AnalyzerRunResult(analyzers[i], error=device_error)
+        return results
+    for i, agg in zip(merge_idx, aggs):
+        try:
+            results[i] = AnalyzerRunResult(
+                analyzers[i], state=analyzers[i].state_from_aggregates(agg)
+            )
+        except Exception as e:  # noqa: BLE001
+            results[i] = AnalyzerRunResult(analyzers[i], error=e)
+    for i, state in zip(assisted_idx, assisted_states):
+        results[i] = AnalyzerRunResult(analyzers[i], state=state)
+    return results
+
+
 class FusedScanPass:
     """Runs a set of scan-shareable analyzers in one device pass."""
 
@@ -2311,27 +2594,12 @@ class FusedScanPass:
                         )
                     )
                 results.update(host_results)  # host outcomes stand on their own
-                if device_error is not None:
-                    # a runtime failure of the shared device program fails
-                    # every analyzer IN that program; host-folded members
-                    # keep their own outcomes
-                    # (reference: AnalysisRunner.scala:310-313)
-                    for i in merge_idx + assisted_idx:
-                        results[i] = AnalyzerRunResult(
-                            self.analyzers[i], error=device_error
-                        )
-                else:
-                    for i, analyzer, agg in zip(merge_idx, merge_analyzers, aggs):
-                        try:
-                            results[i] = AnalyzerRunResult(
-                                analyzer, state=analyzer.state_from_aggregates(agg)
-                            )
-                        except Exception as e:  # noqa: BLE001
-                            results[i] = AnalyzerRunResult(analyzer, error=e)
-                    for i, analyzer, state in zip(
-                        assisted_idx, assisted, assisted_states
-                    ):
-                        results[i] = AnalyzerRunResult(analyzer, state=state)
+                results.update(
+                    device_results(
+                        self.analyzers, merge_idx, assisted_idx, aggs,
+                        assisted_states, device_error,
+                    )
+                )
             except RunCancelled:
                 # deliberate early exit, not an analyzer failure: the
                 # caller resumes from committed partition states
@@ -2457,11 +2725,11 @@ class FusedScanPass:
         table: Table,
         analyzers,
         specs,
-        assisted=(),
-        device_keys=None,
-        host_members=(),
-        host_member_keys=None,
-        host_assisted=(),
+        assisted,
+        device_keys,
+        host_members,
+        host_member_keys,
+        host_assisted,
     ):
         dtype = runtime.compute_dtype()
         use_device = bool(analyzers or assisted)
@@ -2478,8 +2746,6 @@ class FusedScanPass:
                 "counts would lose exactness in the float32 packed "
                 "transfer. Use a smaller batch_size."
             )
-        if device_keys is None:
-            device_keys = set(specs)
         runtime.record_pass(
             "scan:"
             + ",".join(
@@ -2492,29 +2758,7 @@ class FusedScanPass:
         )
 
         sticky: Dict[str, Any] = {}
-        fold = PipelinedAggFold(analyzers, assisted, sticky=sticky)
-        device_spec_keys = sorted(device_keys)
         streaming = bool(getattr(table, "is_streaming", False))
-        # decode-to-wire handshake: the source's attached WireFusionPlan
-        # (None when not planned). After every pack the resolved sticky
-        # shifts publish through it so decode workers can start fusing
-        # shift-needing columns; a device death abandons the handshake.
-        wire_plan = getattr(table, "wire_plan", None)
-
-        # host fold state: per host member, (f64 aggregate, error)
-        host_aggs: Dict[int, Any] = {}
-        host_errors: Dict[int, BaseException] = {}
-        device_error: Optional[BaseException] = None
-
-        all_host = list(host_members) + list(host_assisted)
-        if host_member_keys is None:
-            host_member_keys = {
-                i: [s.key for s in member.input_specs()] for i, member in all_host
-            }
-        host_assisted_states: Dict[int, Any] = {}
-        family_memo: Dict[Any, Any] = {}  # cross-batch, one scan's scope
-        scanned_rows = 0
-        scanned_batches = 0
         batch_size = self.batch_size
         if (
             not use_device
@@ -2556,346 +2800,33 @@ class FusedScanPass:
             ),
             name="fused_scan",
         )
-        ctl = self._controller
+        step = ScanStep(
+            ChipPlacement(
+                analyzers, assisted, sorted(device_keys), self.batch_size,
+                dtype, sticky, getattr(table, "wire_plan", None),
+            )
+            if use_device
+            else None,
+            PipelinedAggFold(analyzers, assisted, sticky=sticky),
+            specs, device_keys, host_members, host_assisted, host_member_keys,
+            streaming=streaming, controller=self._controller,
+            forensics=self._forensics, progress=progress,
+        )
         watchdog = None
-        if ctl is not None:
+        if self._controller is not None:
             wd_s = runtime.stall_watchdog_s()
             if wd_s > 0:
                 # per-stage forensics on stall: the live heartbeat
                 # snapshot (bottleneck/occupancy/readahead) when the
                 # heartbeat runs, else deequ-* thread stacks
                 watchdog = StallWatchdog(
-                    ctl, wd_s, snapshot_fn=progress.snapshot
+                    self._controller, wd_s, snapshot_fn=progress.snapshot
                 ).start()
         try:
-            if streaming and runtime.pipeline_enabled():
-                scanned_rows, scanned_batches, device_error = self._scan_pipelined(
-                    table, batch_size, analyzers, assisted, specs,
-                    device_spec_keys, use_device, dtype, sticky, fold,
-                    host_members, host_assisted, host_member_keys,
-                    host_aggs, host_assisted_states, host_errors, family_memo,
-                    progress=progress,
-                )
-            else:
-                for batch in table.batches(batch_size):
-                    if ctl is not None:
-                        ctl.check(
-                            where="fused_scan batch",
-                            progress={
-                                "batches": scanned_batches,
-                                "rows": scanned_rows,
-                            },
-                        )
-                    # per-key builds with error capture: a failing input (e.g.
-                    # a predicate over a missing column) fails only the
-                    # analyzers that need it — host members individually, the
-                    # device group as a whole (reference:
-                    # AnalysisRunner.scala:310-313). Only keys with a
-                    # still-live consumer are built at all.
-                    live_keys: set = set()
-                    if use_device and device_error is None:
-                        live_keys.update(device_spec_keys)
-                    for i, _member in all_host:
-                        if i not in host_errors:
-                            live_keys.update(host_member_keys[i])
-                    device_live = use_device and device_error is None
-                    host_live = any(i not in host_errors for i, _m in all_host)
-                    if not device_live and not host_live:
-                        break  # everything already failed; stop scanning
-                    # device keys build eagerly (the shared program needs them
-                    # packed); host-only keys build lazily on member access.
-                    # Keys the decode workers already emitted in wire form
-                    # (batch.wire_rows) skip the build entirely.
-                    built = HostInputs(specs, batch)
-                    build_errors = built.build_errors
-                    wire_rows = getattr(batch, "wire_rows", None) or {}
-                    if device_live:
-                        for key in device_spec_keys:
-                            if key not in wire_rows:
-                                built.materialize(key)
-                    if use_device and device_error is None:
-                        try:
-                            with observe.span(
-                                "dispatch", cat="dispatch", rows=batch.num_rows
-                            ) as dispatch_sp:
-                                for key in device_spec_keys:
-                                    if key in build_errors:
-                                        raise build_errors[key]
-                                padded = _pad_size(batch.num_rows, self.batch_size)
-                                packed_inputs, layout = pack_batch_inputs(
-                                    [
-                                        (k, None if k in wire_rows else built[k])
-                                        for k in device_spec_keys
-                                    ],
-                                    padded, dtype, sticky, num_rows=batch.num_rows,
-                                    prepacked=wire_rows,
-                                )
-                                if wire_plan is not None:
-                                    wire_plan.publish_shifts(
-                                        {
-                                            k: float(
-                                                sticky.get(f"shift:{k}", 0.0)
-                                            )
-                                            for k in wire_plan.shift_keys
-                                        }
-                                    )
-                                if dispatch_sp:
-                                    dispatch_sp.set(
-                                        wire_bytes=int(
-                                            sum(
-                                                int(getattr(v, "nbytes", 0))
-                                                for v in packed_inputs.values()
-                                            )
-                                        )
-                                    )
-                                fused, meta_box = get_fused_fn(
-                                    analyzers, assisted, layout
-                                )
-                                runtime.record_launch()
-                                # async dispatch: the device crunches this
-                                # batch while the host folds the previous
-                                # batch (and the host members below)
-                                fold.submit(
-                                    fused(packed_inputs), meta_box, host_ctx=built
-                                )
-                        except Exception as e:  # noqa: BLE001
-                            device_error = e
-                            if wire_plan is not None:
-                                wire_plan.abandon_shifts()
-                    with observe.span("host_fold", cat="host", rows=batch.num_rows):
-                        fold_host_batch(
-                            built, build_errors, host_members, host_assisted,
-                            host_member_keys, host_aggs, host_assisted_states,
-                            host_errors, batch=batch, streaming=streaming,
-                            family_memo=family_memo,
-                        )
-                    if self._forensics is not None:
-                        with observe.span(
-                            "forensics_capture", cat="forensics",
-                            rows=batch.num_rows,
-                        ):
-                            self._forensics.capture_batch(batch, scanned_rows)
-                    scanned_rows += batch.num_rows
-                    scanned_batches += 1
-                    if ctl is not None:
-                        ctl.beat()
-                    progress.advance(batch.num_rows)
+            step.run(table, batch_size)
         finally:
             if watchdog is not None:
                 watchdog.stop()
             progress.finish()
-
-        observe.annotate(rows=scanned_rows, batches=scanned_batches)
-        aggs, assisted_states = [], []
-        if device_error is None:
-            try:
-                # the final device_get lives here: an execution/transfer
-                # failure surfaces now and must not erase host outcomes
-                aggs, assisted_states = fold.finish()
-                shifts = wire_shifts(sticky)
-                if shifts:
-                    aggs = [
-                        a.unshift_agg(agg, shifts)
-                        for a, agg in zip(analyzers, aggs)
-                    ]
-            except Exception as e:  # noqa: BLE001
-                device_error = e
-        host_results = materialize_host_results(
-            host_members, host_assisted, host_aggs, host_assisted_states, host_errors
-        )
-        return aggs, assisted_states, host_results, device_error
-
-    def _scan_pipelined(
-        self,
-        table,
-        batch_size,
-        analyzers,
-        assisted,
-        specs,
-        device_spec_keys,
-        use_device,
-        dtype,
-        sticky,
-        fold,
-        host_members,
-        host_assisted,
-        host_member_keys,
-        host_aggs,
-        host_assisted_states,
-        host_errors,
-        family_memo,
-        progress=observe.heartbeat.NOOP_PROGRESS,
-    ):
-        """The pipelined streaming consumer loop (`DEEQU_TPU_PIPELINE`):
-        per-batch prep — eager device-key builds, wire packing with its
-        H2D put, family kernels — runs on a dedicated stage thread
-        (ops/pipeline.py) ahead of this, the fold stage, which keeps
-        every state mutation (`fold.submit` merges, `fold_host_batch`)
-        in batch order on one thread. Fold order, fold inputs, and the
-        single-threaded sticky-dict mutation are exactly the serial
-        path's, so metrics are bit-identical; only WHERE the prep work
-        runs changes. Liveness feedback to the prep stage (a failed
-        device program, dead host members) lags by the queue depth —
-        in-flight batches may prep work the fold stage then ignores."""
-        all_host = list(host_members) + list(host_assisted)
-        # prep-visible mirror of device_error: set either by a pack
-        # failure on the prep thread or a dispatch/runtime failure here,
-        # so in-flight batches stop paying for device packing
-        device_down = threading.Event()
-        wire_plan = getattr(table, "wire_plan", None)
-
-        def _prep(batch):
-            built = HostInputs(specs, batch)
-            packed_inputs = layout = device_exc = None
-            wire_rows = getattr(batch, "wire_rows", None) or {}
-            if use_device and not device_down.is_set():
-                if wire_plan is not None:
-                    # opens the decode workers' shift_for wait window:
-                    # from here a publish is imminent, so overlapped
-                    # batches briefly wait instead of falling back
-                    wire_plan.mark_pack_started()
-                for key in device_spec_keys:
-                    if key not in wire_rows:
-                        built.materialize(key)
-                try:
-                    with observe.span(
-                        "dispatch",
-                        cat="dispatch",
-                        rows=batch.num_rows,
-                        wire_fuse=len(wire_rows),
-                    ) as dispatch_sp:
-                        for key in device_spec_keys:
-                            if key in built.build_errors:
-                                raise built.build_errors[key]
-                        padded = _pad_size(batch.num_rows, self.batch_size)
-                        # the H2D put happens HERE (jnp.asarray inside):
-                        # batch N+1's wire lands device-side while the
-                        # fold stage still runs batch N. Keys in
-                        # batch.wire_rows splice in the decode workers'
-                        # pre-packed buffers instead of packing here.
-                        packed_inputs, layout = pack_batch_inputs(
-                            [
-                                (k, None if k in wire_rows else built[k])
-                                for k in device_spec_keys
-                            ],
-                            padded, dtype, sticky, num_rows=batch.num_rows,
-                            prepacked=wire_rows,
-                        )
-                        if wire_plan is not None:
-                            # single prep thread: sticky shifts are final
-                            # after this batch's pack — open the decode
-                            # workers' shift gate
-                            wire_plan.publish_shifts(
-                                {
-                                    k: float(sticky.get(f"shift:{k}", 0.0))
-                                    for k in wire_plan.shift_keys
-                                }
-                            )
-                        if dispatch_sp:
-                            dispatch_sp.set(
-                                wire_bytes=int(
-                                    sum(
-                                        int(getattr(v, "nbytes", 0))
-                                        for v in packed_inputs.values()
-                                    )
-                                )
-                            )
-                except Exception as e:  # noqa: BLE001
-                    device_exc = e
-                    packed_inputs = layout = None
-                    device_down.set()
-                    if wire_plan is not None:
-                        wire_plan.abandon_shifts()
-            if any(i not in host_errors for i, _m in all_host):
-                with observe.span(
-                    "host_prep", cat="host", rows=batch.num_rows
-                ):
-                    _precompute_family_kernels(
-                        built, host_assisted, batch,
-                        host_members=host_members, host_errors=host_errors,
-                        streaming=True, family_memo=family_memo,
-                    )
-            return batch, built, packed_inputs, layout, device_exc
-
-        scanned_rows = 0
-        scanned_batches = 0
-        device_error: Optional[BaseException] = None
-        ctl = self._controller
-        items = pipeline.staged(
-            table.batches(batch_size), _prep, name="prep", progress=progress
-        )
-        with contextlib.closing(items):
-            with observe.span(
-                "pipe_stage", cat="pipeline", stage="fold"
-            ) as stage_sp:
-                for item in items:
-                    if ctl is not None:
-                        # raising here unwinds through closing(items):
-                        # the same shutdown contract an exhausted scan
-                        # uses joins every stage thread and fd
-                        ctl.check(
-                            where="pipelined fold batch",
-                            progress={
-                                "batches": scanned_batches,
-                                "rows": scanned_rows,
-                            },
-                        )
-                    batch, built, packed_inputs, layout, device_exc = item
-                    device_live = use_device and device_error is None
-                    host_live = any(i not in host_errors for i, _m in all_host)
-                    if not device_live and not host_live:
-                        break  # everything already failed; stop scanning
-                    with progress.timed("fold"), observe.span(
-                        "pipe_item", cat="pipeline", stage="fold",
-                        rows=batch.num_rows,
-                    ):
-                        if device_live:
-                            if device_exc is not None:
-                                device_error = device_exc
-                            elif packed_inputs is not None:
-                                try:
-                                    # the launch counts as dispatch, as on
-                                    # the serial path; the pack and puts
-                                    # ran in the prep stage's `dispatch`
-                                    with observe.span("launch", cat="dispatch"):
-                                        fused, meta_box = get_fused_fn(
-                                            analyzers, assisted, layout
-                                        )
-                                        runtime.record_launch()
-                                        out = fused(packed_inputs)
-                                    # async dispatch; submit folds the
-                                    # PREVIOUS batch (async D2H landed)
-                                    # while the device crunches this one
-                                    fold.submit(out, meta_box, host_ctx=built)
-                                except Exception as e:  # noqa: BLE001
-                                    device_error = e
-                            if device_error is not None:
-                                device_down.set()
-                                if wire_plan is not None:
-                                    wire_plan.abandon_shifts()
-                        with observe.span(
-                            "host_fold", cat="host", rows=batch.num_rows
-                        ):
-                            fold_host_batch(
-                                built, built.build_errors, host_members,
-                                host_assisted, host_member_keys, host_aggs,
-                                host_assisted_states, host_errors,
-                                batch=batch, streaming=True,
-                                family_memo=family_memo, precomputed=True,
-                            )
-                        if self._forensics is not None:
-                            with observe.span(
-                                "forensics_capture", cat="forensics",
-                                rows=batch.num_rows,
-                            ):
-                                self._forensics.capture_batch(
-                                    batch, scanned_rows
-                                )
-                    scanned_rows += batch.num_rows
-                    scanned_batches += 1
-                    if ctl is not None:
-                        ctl.beat()
-                    progress.advance(batch.num_rows)
-                if stage_sp:
-                    stage_sp.set(items=scanned_batches)
-        return scanned_rows, scanned_batches, device_error
+        observe.annotate(rows=step.rows, batches=step.batches)
+        return step.finish()

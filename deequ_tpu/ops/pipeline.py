@@ -18,16 +18,17 @@ work on its own stage thread with a bounded queue to the fold stage:
   * batch N+1's family kernels and input builds overlap batch N's host
     fold on multicore hosts.
 
-Bit-identity with the serial path (`DEEQU_TPU_PIPELINE=0`): every fold
-(`PipelinedAggFold` merges and `fold_host_batch` member folds) still
-runs on the consumer thread in batch order over the same inputs, and
-the sticky wire dict is only ever mutated by the single prep thread in
-batch order — the pipeline changes WHERE per-batch work runs, never
+Bit-identity with the inline driver an in-memory table takes
+(`ops/fused.py:ScanStep`, the same per-batch step on one thread): every
+fold (`PipelinedAggFold` merges and `fold_host_batch` member folds)
+still runs on the consumer thread in batch order over the same inputs,
+and the sticky wire dict is only ever mutated by the single prep thread
+in batch order — the pipeline changes WHERE per-batch work runs, never
 what is computed. The one permitted divergence: liveness feedback lags
 by at most the queue depth, so a member that errors mid-stream can have
 its family kernel still run for the batches already in flight — wasted
 work on an already-failing plan, not a results change on healthy
-streams (the pipeline-on/off differential in
+streams (the streamed-vs-in-memory differential in
 tests/test_suite_differential_fuzz.py pins bit-identical metrics).
 
 Stage threads must never host-sync: `jax.device_get` /
@@ -49,7 +50,7 @@ in the DECODE stage's busy time (where the arrow_decode spans live).
 The occupancy report therefore re-baselines when fusion toggles —
 decode busy_s rises by roughly the pack time that prep loses, and the
 total stays accounted: time moves between stage buckets, it is never
-dropped (BENCH.md's round-10 table shows the A/B).
+dropped.
 """
 
 from __future__ import annotations

@@ -97,9 +97,8 @@ _CHECKOUT_CACHE_DIR = os.path.join(
 
 def use_compile_cache() -> str:
     """Turn on JAX's persistent compilation cache for this process and
-    return its directory. Entry points (chip_smoke.py, bench.py, tools/)
-    call this before their first compile; the library never does on
-    import. Where `JAX_COMPILATION_CACHE_DIR` is set, JAX already reads
+    return its directory. chip_smoke.py calls this before its first
+    compile; the library never does on import. Where `JAX_COMPILATION_CACHE_DIR` is set, JAX already reads
     it and nothing is changed; otherwise the cache lives at
     `<checkout>/.jax_cache`."""
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
@@ -208,21 +207,6 @@ PLACEMENT_CACHE_TTL_S = 7 * 24 * 3600
 # ---------------------------------------------------------------------------
 
 DEFAULT_PIPELINE_DEPTH = 2
-
-
-def pipeline_enabled() -> bool:
-    """Whether streaming scans run the backpressured stage pipeline
-    (ops/pipeline.py): per-batch prep work — input builds, wire packing
-    with its H2D put, family kernels — moves onto a dedicated stage
-    thread that runs ahead of the consumer's ordered fold, so batch
-    N+1's transfer/host work overlaps batch N's compute.
-
-    `DEEQU_TPU_PIPELINE=0` (or `off`) forces the serial path, which is
-    bit-identical: the pipeline changes WHERE per-batch work runs, never
-    what is computed or the fold order."""
-    import os
-
-    return os.environ.get("DEEQU_TPU_PIPELINE", "") not in ("0", "off")
 
 
 def pushdown_enabled() -> bool:
@@ -599,10 +583,8 @@ def source_stall_s() -> float:
     pipeline against sources with real per-read wait — object-store GETs,
     network filesystems — on boxes whose local disk is too fast (and
     whose kernel readahead too good) for decode/IO overlap to matter.
-    The stall is paid by whichever thread runs the decode: the caller
-    under `DEEQU_TPU_PIPELINE=0`, the decode stage thread when pipelined
-    — so an A/B with the knob set measures exactly how much source wait
-    the pipeline hides. Never set it for real-throughput numbers."""
+    The stall is paid by the decode stage thread, where a remote read
+    would block. Never set it for real-throughput numbers."""
     import os
 
     raw = os.environ.get("DEEQU_TPU_SOURCE_STALL_MS", "")

@@ -17,7 +17,6 @@ only state pytrees (bytes to KB) ever cross host boundaries, never rows.
 
 from __future__ import annotations
 
-import contextlib
 import threading
 from typing import Any, Dict, List, Optional, Sequence
 
@@ -29,16 +28,14 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from deequ_tpu import observe
 from deequ_tpu.analyzers.base import ScanShareableAnalyzer
 from deequ_tpu.data.table import Table
-from deequ_tpu.ops import pipeline, runtime
+from deequ_tpu.ops import runtime
 from deequ_tpu.ops.fused import (
     AnalyzerRunResult,
-    HostInputs,
     PipelinedAggFold,
+    ScanStep,
     _pad_size,
-    _precompute_family_kernels,
     apply_decode_plan,
-    fold_host_batch,
-    materialize_host_results,
+    device_results,
     plan_decode_fastpath,
     plan_scan_members,
     prune_table_columns,
@@ -131,6 +128,50 @@ def _shard_rows(inputs: Dict[str, Any], n_devices: int) -> int:
     return 0
 
 
+class MeshPlacement:
+    """How a batch's device inputs reach the mesh: each device key padded,
+    narrowed and shifted exactly like the single-chip wire, then put with
+    the mesh sharding, and one launch of the sharded program."""
+
+    wire_plan = None  # the mesh packs no decode-fused wire
+
+    def __init__(self, fn, mesh, axis_name, keys, batch_size_per_device, dtype, sticky):
+        self.fn, self.keys, self.dtype, self.sticky = fn, keys, dtype, sticky
+        self.n_devices = mesh.shape[axis_name]
+        self.batch_size_per_device = batch_size_per_device
+        self.sharding = NamedSharding(mesh, P(axis_name))
+
+    def attrs(self, batch) -> Dict[str, Any]:
+        return {"devices": int(self.n_devices)}
+
+    def place(self, batch, built):
+        # pad to a multiple of n_devices (pow2 per shard)
+        per_dev = _pad_size(
+            -(-batch.num_rows // self.n_devices), self.batch_size_per_device
+        )
+        padded = per_dev * self.n_devices
+        inputs: Dict[str, Any] = {}
+        for key in self.keys:
+            arr = runtime.pad_to(built[key], padded)
+            if np.issubdtype(arr.dtype, np.integer):
+                arr = runtime.narrow_int_wire(arr, key, self.sticky)
+            elif arr.dtype != np.bool_:
+                if np.dtype(self.dtype) == np.float32 and key.startswith("num:"):
+                    # same f32 pre-centering as pack_batch_inputs
+                    shift = resolve_shift(key, arr, self.sticky, built.get)
+                    if shift != 0.0:
+                        arr = np.asarray(arr, dtype=np.float64) - shift
+                arr = arr.astype(self.dtype)
+            inputs[key] = jax.device_put(arr, self.sharding)
+        return inputs, None
+
+    def launch(self, inputs, _aux):
+        """Start the program -> (device outputs, meta box, shard rows)."""
+        runtime.record_launch()
+        runtime.record_placement(inputs.values())
+        return self.fn(inputs), None, _shard_rows(inputs, self.n_devices)
+
+
 class DistributedScanPass:
     """Mesh-sharded variant of FusedScanPass: device-reduced analyzers
     merge in-graph via collectives; device-assisted analyzers (quantile
@@ -171,11 +212,7 @@ class DistributedScanPass:
         assisted_idx = plan.assisted_idx
         merge_analyzers = [self.analyzers[i] for i in merge_idx]
         assisted = [self.analyzers[i] for i in assisted_idx]
-        host_members = [(i, self.analyzers[i]) for i in plan.host_idx]
-        host_assisted = [(i, self.analyzers[i]) for i in plan.host_assisted_idx]
-        host_member_keys = plan.host_keys
         specs = plan.specs
-        device_keys = plan.device_keys
 
         table = prune_table_columns(table, specs)
         # decode routing after pruning, exactly as in FusedScanPass: the
@@ -185,278 +222,46 @@ class DistributedScanPass:
         if decode_plan is not None:
             table = apply_decode_plan(table, decode_plan)
         n_devices = self.mesh.shape[self.axis_name]
-        global_batch = self.batch_size_per_device * n_devices
-        dtype = runtime.compute_dtype()
-        fn = (
-            _get_distributed_fn(
-                merge_analyzers, self.mesh, self.axis_name, assisted
+        sticky: Dict[str, Any] = {}
+        placement = None
+        if merge_analyzers or assisted:
+            placement = MeshPlacement(
+                _get_distributed_fn(
+                    merge_analyzers, self.mesh, self.axis_name, assisted
+                ),
+                self.mesh, self.axis_name, sorted(plan.device_keys),
+                self.batch_size_per_device, runtime.compute_dtype(), sticky,
             )
-            if merge_analyzers or assisted
-            else None
-        )
         runtime.record_pass(
             f"dist-scan[{n_devices}x]:"
             + ",".join(a.name for a in self.analyzers)
         )
-        in_sharding = jax.tree_util.tree_map(
-            lambda _: NamedSharding(self.mesh, P(self.axis_name)), specs
-        )
-
-        host_aggs: Dict[int, Any] = {}
-        host_assisted_states: Dict[int, Any] = {}
-        host_errors: Dict[int, BaseException] = {}
-        sticky: Dict[str, Any] = {}
-        family_memo: Dict[Any, Any] = {}  # cross-batch, one scan's scope
-        streaming = bool(getattr(table, "is_streaming", False))
         try:
-            fold = PipelinedAggFold(
-                merge_analyzers, assisted, n_dev=n_devices, sticky=sticky
+            step = ScanStep(
+                placement,
+                PipelinedAggFold(
+                    merge_analyzers, assisted, n_dev=n_devices, sticky=sticky
+                ),
+                specs, plan.device_keys,
+                [(i, self.analyzers[i]) for i in plan.host_idx],
+                [(i, self.analyzers[i]) for i in plan.host_assisted_idx],
+                plan.host_keys,
+                streaming=bool(getattr(table, "is_streaming", False)),
             )
-
-            all_host = list(host_members) + list(host_assisted)
-
-            def _shard_inputs(batch, built) -> Dict[str, Any]:
-                """Pad/narrow/shift each device key exactly like the
-                single-chip wire, then place it with the mesh sharding —
-                the H2D put the pipeline overlaps with compute."""
-                for key in device_keys:
-                    if key in built.build_errors:
-                        raise built.build_errors[key]
-                # pad to a multiple of n_devices (pow2 per shard)
-                per_dev = _pad_size(
-                    -(-batch.num_rows // n_devices),
-                    self.batch_size_per_device,
-                )
-                padded = per_dev * n_devices
-                inputs: Dict[str, Any] = {}
-                for key in device_keys:
-                    arr = runtime.pad_to(built[key], padded)
-                    if np.issubdtype(arr.dtype, np.integer):
-                        arr = runtime.narrow_int_wire(arr, key, sticky)
-                    elif arr.dtype != np.bool_:
-                        if (
-                            np.dtype(dtype) == np.float32
-                            and key.startswith("num:")
-                        ):
-                            # same f32 pre-centering as
-                            # pack_batch_inputs (see fused.py)
-                            shift = resolve_shift(key, arr, sticky, built.get)
-                            if shift != 0.0:
-                                arr = np.asarray(arr, dtype=np.float64) - shift
-                        arr = arr.astype(dtype)
-                    inputs[key] = jax.device_put(arr, in_sharding[key])
-                return inputs
-
-            device_error: Any = None
-            if streaming and runtime.pipeline_enabled():
-                device_error = self._scan_pipelined(
-                    table, global_batch, fn, specs, device_keys, n_devices,
-                    _shard_inputs, fold, all_host, host_members,
-                    host_assisted, host_member_keys, host_aggs,
-                    host_assisted_states, host_errors, family_memo,
-                )
-            else:
-                for batch in table.batches(global_batch):
-                    # per-key builds with error capture — same isolation
-                    # contract as FusedScanPass._run_pass; host-only keys
-                    # build lazily (fused.HostInputs)
-                    device_live = fn is not None and device_error is None
-                    host_live = any(
-                        i not in host_errors for i, _m in all_host
-                    )
-                    if not device_live and not host_live:
-                        break  # everything already failed; stop scanning
-                    built = HostInputs(specs, batch)
-                    build_errors = built.build_errors
-                    if device_live:
-                        for key in sorted(device_keys):
-                            built.materialize(key)
-                    if fn is not None and device_error is None:
-                        try:
-                            with observe.span(
-                                "dispatch",
-                                cat="dispatch",
-                                rows=batch.num_rows,
-                                devices=int(n_devices),
-                            ) as dispatch_sp:
-                                inputs = _shard_inputs(batch, built)
-                                if dispatch_sp:
-                                    dispatch_sp.set(
-                                        wire_bytes=sum(
-                                            int(getattr(v, "nbytes", 0))
-                                            for v in inputs.values()
-                                        )
-                                    )
-                                runtime.record_launch()
-                                runtime.record_placement(inputs.values())
-                                fold.submit(
-                                    fn(inputs),
-                                    host_ctx=built,
-                                    shard_rows=_shard_rows(inputs, n_devices),
-                                )
-                        except Exception as e:  # noqa: BLE001
-                            device_error = e
-                    with observe.span(
-                        "host_fold", cat="host", rows=batch.num_rows
-                    ):
-                        fold_host_batch(
-                            built, build_errors, host_members, host_assisted,
-                            host_member_keys, host_aggs, host_assisted_states,
-                            host_errors,
-                            batch=batch, streaming=streaming,
-                            family_memo=family_memo,
-                        )
-            aggs, assisted_states = [], []
-            if device_error is None:
-                try:
-                    aggs, assisted_states = fold.finish()
-                    from deequ_tpu.ops.fused import wire_shifts
-
-                    shifts = wire_shifts(sticky)
-                    if shifts:
-                        aggs = [
-                            a.unshift_agg(agg, shifts)
-                            for a, agg in zip(merge_analyzers, aggs)
-                        ]
-                except Exception as e:  # noqa: BLE001
-                    device_error = e
-            if device_error is not None:
-                for i in merge_idx + assisted_idx:
-                    results[i] = AnalyzerRunResult(
-                        self.analyzers[i], error=device_error
-                    )
-            else:
-                for i, analyzer, agg in zip(merge_idx, merge_analyzers, aggs):
-                    try:
-                        results[i] = AnalyzerRunResult(
-                            analyzer, state=analyzer.state_from_aggregates(agg)
-                        )
-                    except Exception as e:  # noqa: BLE001
-                        results[i] = AnalyzerRunResult(analyzer, error=e)
-                for i, state in zip(assisted_idx, assisted_states):
-                    results[i] = AnalyzerRunResult(self.analyzers[i], state=state)
+            step.run(table, self.batch_size_per_device * n_devices)
+            aggs, assisted_states, host_results, device_error = step.finish()
             results.update(
-                materialize_host_results(
-                    host_members, host_assisted, host_aggs,
-                    host_assisted_states, host_errors,
+                device_results(
+                    self.analyzers, merge_idx, assisted_idx, aggs,
+                    assisted_states, device_error,
                 )
             )
+            results.update(host_results)
         except Exception as e:  # noqa: BLE001
             for i in range(len(self.analyzers)):
                 results.setdefault(i, AnalyzerRunResult(self.analyzers[i], error=e))
 
         return [results[i] for i in range(len(self.analyzers))]
-
-    def _scan_pipelined(
-        self,
-        table,
-        global_batch,
-        fn,
-        specs,
-        device_keys,
-        n_devices,
-        shard_inputs,
-        fold,
-        all_host,
-        host_members,
-        host_assisted,
-        host_member_keys,
-        host_aggs,
-        host_assisted_states,
-        host_errors,
-        family_memo,
-    ):
-        """Sharded-stream twin of `FusedScanPass._scan_pipelined`: the
-        per-batch prep — eager builds, pad/narrow/shift, the sharded
-        `jax.device_put` — runs on a stage thread so batch N+1's H2D
-        lands on the mesh while batch N's collectives run; every fold
-        stays on this thread in batch order (bit-identical to serial)."""
-        device_down = threading.Event()
-
-        def _prep(batch):
-            built = HostInputs(specs, batch)
-            inputs = device_exc = None
-            if fn is not None and not device_down.is_set():
-                for key in sorted(device_keys):
-                    built.materialize(key)
-                try:
-                    with observe.span(
-                        "dispatch",
-                        cat="dispatch",
-                        rows=batch.num_rows,
-                        devices=int(n_devices),
-                    ) as dispatch_sp:
-                        inputs = shard_inputs(batch, built)
-                        if dispatch_sp:
-                            dispatch_sp.set(
-                                wire_bytes=sum(
-                                    int(getattr(v, "nbytes", 0))
-                                    for v in inputs.values()
-                                )
-                            )
-                except Exception as e:  # noqa: BLE001
-                    device_exc = e
-                    inputs = None
-                    device_down.set()
-            if any(i not in host_errors for i, _m in all_host):
-                with observe.span(
-                    "host_prep", cat="host", rows=batch.num_rows
-                ):
-                    _precompute_family_kernels(
-                        built, host_assisted, batch,
-                        host_members=host_members, host_errors=host_errors,
-                        streaming=True, family_memo=family_memo,
-                    )
-            return batch, built, inputs, device_exc
-
-        device_error: Any = None
-        items = pipeline.staged(table.batches(global_batch), _prep, name="prep")
-        with contextlib.closing(items):
-            with observe.span(
-                "pipe_stage", cat="pipeline", stage="fold"
-            ) as stage_sp:
-                n_items = 0
-                for batch, built, inputs, device_exc in items:
-                    device_live = fn is not None and device_error is None
-                    host_live = any(i not in host_errors for i, _m in all_host)
-                    if not device_live and not host_live:
-                        break  # everything already failed; stop scanning
-                    with observe.span(
-                        "pipe_item", cat="pipeline", stage="fold",
-                        rows=batch.num_rows,
-                    ):
-                        if device_live:
-                            if device_exc is not None:
-                                device_error = device_exc
-                            elif inputs is not None:
-                                try:
-                                    runtime.record_launch()
-                                    runtime.record_placement(inputs.values())
-                                    fold.submit(
-                                        fn(inputs),
-                                        host_ctx=built,
-                                        shard_rows=_shard_rows(
-                                            inputs, n_devices
-                                        ),
-                                    )
-                                except Exception as e:  # noqa: BLE001
-                                    device_error = e
-                            if device_error is not None:
-                                device_down.set()
-                        with observe.span(
-                            "host_fold", cat="host", rows=batch.num_rows
-                        ):
-                            fold_host_batch(
-                                built, built.build_errors, host_members,
-                                host_assisted, host_member_keys, host_aggs,
-                                host_assisted_states, host_errors,
-                                batch=batch, streaming=True,
-                                family_memo=family_memo, precomputed=True,
-                            )
-                    n_items += 1
-                if stage_sp:
-                    stage_sp.set(items=n_items)
-        return device_error
 
 
 _BINCOUNT_CACHE: Dict[Any, Any] = {}

@@ -1,6 +1,6 @@
 """Seeded TPC-H `lineitem`-shaped table (BASELINE.json config 3).
 
-Shared by `bench.py` and `chip_smoke.py`. The 16 columns, their types and
+Shared by `chip_smoke.py` and the Pallas kernel tests. The 16 columns, their types and
 their value domains follow the TPC-H specification (§1.4.1 and §4.2.3):
 
 * each order has 1-7 lines, numbered from 1, so (l_orderkey, l_linenumber)

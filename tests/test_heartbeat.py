@@ -54,7 +54,6 @@ class TestHeartbeatOnStreamedScan:
         monkeypatch.setenv("DEEQU_TPU_HEARTBEAT_S", "0.02")
         # stall decode 10ms/row-group so the scan outlives a few beats
         monkeypatch.setenv("DEEQU_TPU_SOURCE_STALL_MS", "10")
-        monkeypatch.setenv("DEEQU_TPU_PIPELINE", "1")
         snaps = []
         cb = snaps.append
         heartbeat.register_callback(cb)

@@ -262,8 +262,7 @@ def test_disabled_chaos_and_controller_overhead_under_two_percent():
     bounded analytically like the guards above: batch count from a
     traced run (host_fold spans), ×32 headroom to cover every per-unit
     fetch/decode seam, per-row-group retries, and the per-partition
-    checks. BENCH_CHAOS.json (make bench-chaos) pins the same bound on
-    a real A/B wall-clock run."""
+    checks."""
     from deequ_tpu.testing import faults
 
     assert faults.active_plan() is None

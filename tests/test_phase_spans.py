@@ -39,7 +39,6 @@ def parquet_path(tmp_path):
 @pytest.fixture
 def on_device(monkeypatch):
     monkeypatch.setenv("DEEQU_TPU_PLACEMENT", "device")
-    monkeypatch.setenv("DEEQU_TPU_PIPELINE", "1")
 
 
 def _check():

@@ -15,7 +15,7 @@ Two failure directions, both previously untested:
 
 Covers `DataSource.batches` (data/source.py) and `pipeline.staged`
 (ops/pipeline.py), separately and stacked (staged over batches —
-the shape `FusedScanPass._scan_pipelined` runs).
+the shape `ScanStep.run` drives for a streamed source).
 """
 
 from __future__ import annotations

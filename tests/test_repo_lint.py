@@ -121,8 +121,8 @@ def test_timing_checker_allows_wall_clock_and_observe():
 
 def test_timing_rule_scopes_to_engine_dirs():
     """The ban covers deequ_tpu/runners + deequ_tpu/ops only — observe/
-    (the timing implementation itself) and bench.py stay free to read
-    clocks directly."""
+    (the timing implementation itself) and the benchmark stay free to
+    read clocks directly."""
     lint = _lint_module()
     sep = os.sep
     covered = f"deequ_tpu{sep}ops{sep}runtime.py"

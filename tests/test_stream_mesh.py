@@ -175,3 +175,47 @@ def test_stream_profile_equals_in_memory(parquet_path, in_memory):
             assert {
                 (k, v.absolute) for k, v in s.histogram.values.items()
             } == {(k, v.absolute) for k, v in m.histogram.values.items()}
+
+
+@pytest.mark.parametrize("streamed", [True, False])
+def test_mesh_program_failure_keeps_host_members(
+    parquet_path, in_memory, streamed, monkeypatch
+):
+    """The sharded program failing mid-scan fails only the analyzers in
+    it: the host-folded members still return the in-memory single-device
+    results, over a stream (staged driver) as over a table (inline)."""
+    from deequ_tpu.parallel import distributed
+
+    monkeypatch.setenv("DEEQU_TPU_PLACEMENT", "host-discrete")
+    real = distributed._get_distributed_fn
+    calls = []
+
+    def failing_fn(*args, **kwargs):
+        fn = real(*args, **kwargs)
+
+        def run(inputs):
+            calls.append(1)
+            if len(calls) == 2:
+                raise RuntimeError("injected program failure")
+            return fn(inputs)
+
+        return run
+
+    monkeypatch.setattr(distributed, "_get_distributed_fn", failing_fn)
+    host = [Size(), Completeness("x"), ApproxCountDistinct("cat")]
+    device = [Mean("x"), Sum("x")]
+    data = ParquetSource(parquet_path, batch_rows=1 << 15) if streamed else in_memory
+    results = DistributedScanPass(
+        host + device, mesh=data_mesh(), batch_size_per_device=1 << 12
+    ).run(data)
+    assert len(calls) >= 2, "the program never ran a second batch"
+
+    single = AnalysisRunner.do_analysis_run(in_memory, host, engine="single")
+    for result in results[: len(host)]:
+        got = result.analyzer.compute_metric_from(result.state_or_raise())
+        assert got.value.get() == pytest.approx(
+            single.metric_map[result.analyzer].value.get(), rel=1e-12
+        ), result.analyzer
+    for result in results[len(host):]:
+        assert isinstance(result.error, RuntimeError), result.analyzer
+        assert "injected program failure" in str(result.error)

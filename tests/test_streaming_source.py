@@ -347,8 +347,8 @@ class TestStreamingParity:
         assert hist == dict(collections.Counter(table.column("c").to_pylist()))
 
     def test_source_stall_knob_is_inert_on_results(self, tmp_path, monkeypatch):
-        """DEEQU_TPU_SOURCE_STALL_MS (the object-store latency model used
-        by bench.py's pipeline A/B) delays the decoding thread but must
+        """DEEQU_TPU_SOURCE_STALL_MS (the object-store latency model)
+        delays the decoding thread but must
         never change what the stream yields — same batches, same metrics
         — and malformed values fall back to off."""
         from deequ_tpu.ops import runtime

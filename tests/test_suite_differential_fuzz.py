@@ -75,7 +75,7 @@ def random_table(rng: np.random.Generator) -> Table:
 
 
 def wide_table(rng: np.random.Generator) -> Table:
-    """50-column layout (the BENCH_STREAM_1B_WIDE shape, shrunk): 20
+    """50-column layout (the wide stream shape, shrunk): 20
     doubles, 15 longs at mixed cardinalities, 10 dictionary-encoded
     strings, 5 low-cardinality floats — so the counts fast paths,
     dictionary memos, int narrowing and the stream pipeline's packing
@@ -406,35 +406,30 @@ def test_suite_agrees_streamed_vs_in_memory(seed, monkeypatch, tmp_path):
     assert_snapshots_agree(in_memory, streamed, "memory-vs-stream")
 
 
-# -- layout fuzz + the pipeline on/off differential (ISSUE 5) ----------------
-
-
-def _count_spans(roots, name: str) -> int:
-    total = 0
-    stack = list(roots)
-    while stack:
-        sp = stack.pop()
-        if sp.name == name:
-            total += 1
-        stack.extend(sp.children)
-    return total
+# -- layout fuzz + the streamed vs in-memory differential --------------------
 
 
 @pytest.mark.parametrize(
     "layout,seed",
     [(layout, seed) for layout in ("narrow", "wide", "lineitem") for seed in range(4)],
 )
-def test_pipeline_on_off_bit_identical(layout, seed, monkeypatch, tmp_path):
-    """The DEEQU_TPU_PIPELINE=0 serial fallback must be BIT-identical to
-    the pipelined streaming path — exact snapshot equality, sketches
-    included (same engine, same fold order, same inputs: nothing may
-    diverge). Runs every layout so wide packing, dictionary memos and
-    the lineitem fast paths all cross the stage boundary. Also pins
+def test_streamed_scan_bit_identical_to_in_memory(layout, seed, monkeypatch, tmp_path):
+    """A streamed scan (the staged driver: prep a batch ahead on its own
+    thread) must be BIT-identical to the in-memory Table of the same
+    rows (the inline driver) — exact equality, sketches included: the
+    same per-batch step folds the same inputs in the same order. Over
+    one batch the whole suite is compared; over five, the fused pass
+    alone at an explicit batch size, so the sticky wire shifts, the
+    cross-batch family memo and the ordered device fold all cross the
+    stage boundary. Runs every layout so wide packing, dictionary memos
+    and the lineitem fast paths all take both drivers. Also pins
     tracing-inertness: running under a tracer must not change one bit
     of the result, and the trace must show the pipeline actually
     engaged (pipe_stage spans for every stage)."""
     from deequ_tpu import observe
+    from deequ_tpu.analyzers.base import ScanShareableAnalyzer
     from deequ_tpu.data.table import Table as TableCls
+    from deequ_tpu.ops.fused import FusedScanPass
 
     rng = np.random.default_rng(11_000 + seed)
     table = LAYOUTS[layout](rng)
@@ -443,29 +438,24 @@ def test_pipeline_on_off_bit_identical(layout, seed, monkeypatch, tmp_path):
     checks = [random_check(rng, roles) for _ in range(int(rng.integers(1, 3)))]
     # alternate placements so both the H2D prep path (device) and the
     # family-kernel host path cross the pipeline's stage boundary
-    placement = "device" if seed % 2 else "host"
+    monkeypatch.setenv("DEEQU_TPU_PLACEMENT", "device" if seed % 2 else "host")
 
-    path = str(tmp_path / "fuzz.parquet")
-    table.to_parquet(
-        path, row_group_size=max(64, n // 7), dictionary_encode_strings=True
-    )
+    one_batch = str(tmp_path / "one.parquet")
+    table.to_parquet(one_batch, row_group_size=n, dictionary_encode_strings=True)
 
-    def run(pipeline_env):
-        monkeypatch.setenv("DEEQU_TPU_PLACEMENT", placement)
-        monkeypatch.setenv("DEEQU_TPU_PIPELINE", pipeline_env)
-        data = TableCls.scan_parquet(path, batch_rows=max(64, n // 5))
+    def run(data):
         builder = VerificationSuite().on_data(data)
         for check in checks:
             builder = builder.add_check(check)
         return suite_snapshot(builder.with_engine("single").run())
 
-    serial = run("0")
-    pipelined = run("1")
-    assert serial == pipelined, (layout, seed, placement)
+    in_memory = run(table)
+    streamed = run(TableCls.scan_parquet(one_batch, batch_rows=n))
+    assert streamed == in_memory, (layout, seed)
 
     with observe.tracing() as tracer:
-        traced = run("1")
-    assert traced == pipelined, ("tracing changed results", layout, seed)
+        traced = run(TableCls.scan_parquet(one_batch, batch_rows=n))
+    assert traced == streamed, ("tracing changed results", layout, seed)
     stages = {
         sp.attrs.get("stage")
         for root in tracer.roots
@@ -475,6 +465,40 @@ def test_pipeline_on_off_bit_identical(layout, seed, monkeypatch, tmp_path):
     assert {"decode", "prep", "fold"} <= stages, (
         "pipeline did not engage under tracing",
         stages,
+    )
+
+    # many batches: an explicit batch size holds both drivers to the
+    # same batch boundaries (one row group per batch)
+    batch = max(64, n // 5)
+    many = str(tmp_path / "many.parquet")
+    table.to_parquet(many, row_group_size=batch, dictionary_encode_strings=True)
+    analyzers = list(
+        {
+            a
+            for check in checks
+            for a in check.required_analyzers()
+            if isinstance(a, ScanShareableAnalyzer)
+        }
+    )
+
+    def scan(data):
+        out = {}
+        for r in FusedScanPass(analyzers, batch_size=batch).run(data):
+            value = (
+                r.analyzer.compute_metric_from(r.state).value
+                if r.error is None
+                else None
+            )
+            out[repr(r.analyzer)] = (
+                repr(value.get())
+                if value is not None and value.is_success
+                else repr(r.error)
+            )
+        return out
+
+    assert scan(TableCls.scan_parquet(many, batch_rows=batch)) == scan(table), (
+        layout,
+        seed,
     )
 
 
@@ -1106,11 +1130,14 @@ def test_state_cache_drift_pins_zero_and_traces(monkeypatch, tmp_path):
 def test_forensics_on_off_bit_identical(seed, monkeypatch, tmp_path):
     """with_forensics() must be provably inert: exact snapshot equality
     (metrics, check statuses, sketches included) with row-level capture
-    on vs off — on both placements, with the streaming pipeline on and
-    off, and through a state-cache cold fill and all-hit warm run
+    on vs off — on both placements, over the streamed dataset and the
+    same rows in memory, and through a state-cache cold fill and all-hit warm run
     (cached partitions reduce forensics to provenance, never change
     results). Capture reads the decoded batch through its own masks and
     never touches the fold inputs, so nothing may diverge by one bit."""
+    import pyarrow as pa
+    import pyarrow.parquet as pq
+
     from deequ_tpu.data.table import Table as TableCls
     from deequ_tpu.repository.states import FileSystemStateRepository
 
@@ -1121,12 +1148,21 @@ def test_forensics_on_off_bit_identical(seed, monkeypatch, tmp_path):
     for i in range(3):
         _write_partition(random_table(rng), str(data_dir / f"part-{i}.parquet"))
     repo = FileSystemStateRepository(str(tmp_path / "cache"))
+    # the same rows in memory: the inline driver's side of the matrix
+    in_memory = TableCls.from_arrow(
+        pa.concat_tables(
+            [pq.read_table(str(p)) for p in sorted(data_dir.iterdir())]
+        )
+    )
 
-    def run(placement, pipeline, forensics, cached=False):
+    def run(placement, streamed, forensics, cached=False):
         monkeypatch.setenv("DEEQU_TPU_PLACEMENT", placement)
-        monkeypatch.setenv("DEEQU_TPU_PIPELINE", pipeline)
         monkeypatch.setenv("DEEQU_TPU_STATE_CACHE", "1" if cached else "0")
-        data = TableCls.scan_parquet_dataset(str(data_dir))
+        data = (
+            TableCls.scan_parquet_dataset(str(data_dir))
+            if streamed
+            else in_memory
+        )
         builder = VerificationSuite().on_data(data)
         for check in checks:
             builder = builder.add_check(check)
@@ -1140,17 +1176,17 @@ def test_forensics_on_off_bit_identical(seed, monkeypatch, tmp_path):
         return suite_snapshot(result)
 
     for placement in ("host", "device"):
-        for pipeline in ("0", "1"):
-            off = run(placement, pipeline, False)
-            on = run(placement, pipeline, True)
-            assert off == on, (seed, placement, pipeline)
+        for streamed in (True, False):
+            off = run(placement, streamed, False)
+            on = run(placement, streamed, True)
+            assert off == on, (seed, placement, streamed)
 
-    baseline = run("host", "1", False)
+    baseline = run("host", True, False)
     # cold: capture rides the scans that fill the cache
-    assert run("host", "1", True, cached=True) == baseline, (seed, "cold")
+    assert run("host", True, True, cached=True) == baseline, (seed, "cold")
     # warm: every partition merges from cache; capture sees no batches
-    assert run("host", "1", True, cached=True) == baseline, (seed, "warm-on")
-    assert run("host", "1", False, cached=True) == baseline, (seed, "warm-off")
+    assert run("host", True, True, cached=True) == baseline, (seed, "warm-on")
+    assert run("host", True, False, cached=True) == baseline, (seed, "warm-off")
 
 
 # -- chaos differential: injected faults change nothing (ISSUE 13) -----------
@@ -1192,7 +1228,6 @@ def test_chaos_faults_bit_identical_both_placements(spec, monkeypatch, tmp_path)
 
     def run(placement):
         monkeypatch.setenv("DEEQU_TPU_PLACEMENT", placement)
-        monkeypatch.setenv("DEEQU_TPU_PIPELINE", "1")
         # the worker-pool decode path needs >1 worker on a 1-core box
         monkeypatch.setenv("DEEQU_TPU_DECODE_WORKERS", "2")
         data = TableCls.scan_parquet(

@@ -29,7 +29,7 @@ Checks:
   OBSPRINT — no `print(...)` calls in deequ_tpu/observe/: heartbeat and
              trace output must flow through a sink, callback, or
              explicit stream write (`sys.stderr.write`) — stdout
-             belongs to results (bench.py's one-JSON-line contract) and
+             belongs to results (benchmark/run.py's result lines) and
              a stray print corrupts any caller parsing it.
   PUSHDOWN — deequ_tpu/lint/pushdown.py must stay a pure interpreter:
              no pyarrow/pandas import (not even lazily inside a

@@ -6,7 +6,7 @@ anomaly strategies over them, exiting nonzero with a human-readable
 verdict when throughput or phase shares regress:
 
   * a metrics repository JSON file (default `ENGINE_METRICS.json` at the
-    repo root — what bench.py appends to; see BENCH.md), filtered to
+    repo root), filtered to
     `telemetry=engine` result keys via `deequ_tpu.repository.engine`;
   * the committed `BENCH_r0*.json` history (headline rows/s per round).
 
