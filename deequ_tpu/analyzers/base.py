@@ -189,22 +189,38 @@ class Analyzer:
         aggregate_with: Optional["StateLoader"] = None,
         save_states_with: Optional["StatePersister"] = None,
     ) -> Metric:
-        # the state layer: `state_load` (read and deserialize),
-        # `state_merge`, `state_save` (serialize and write); no span opens
-        # without a loader or a persister
+        # the state layer, one step after another on this thread; the
+        # runner's scan runs the same steps, its persists on a pool where
+        # the persister allows (runners/state_io.py)
         if aggregate_with is not None:
-            with observe.span("state_load", cat="state", analyzer=self.name):
-                loaded = aggregate_with.load(self)
-            if loaded is not None:
-                if state is None:
-                    state = loaded
-                else:
-                    with observe.span("state_merge", cat="state", analyzer=self.name):
-                        state = loaded.merge(state)
+            state = self.merge_loaded(state, self.load_state(aggregate_with))
         if save_states_with is not None and state is not None:
-            with observe.span("state_save", cat="state", analyzer=self.name):
-                save_states_with.persist(self, state)
+            self.save_state(save_states_with, state)
         return self.compute_metric_from(state)
+
+    # the state layer's steps, each in its span (category `state`); no
+    # span opens without a loader or a persister
+
+    def load_state(self, loader: "StateLoader") -> Optional[State]:
+        """`state_load`: the loader's read and deserialization."""
+        with observe.span("state_load", cat="state", analyzer=self.name):
+            return loader.load(self)
+
+    def merge_loaded(
+        self, state: Optional[State], loaded: Optional[State]
+    ) -> Optional[State]:
+        """`state_merge`: the stored state merged with this run's."""
+        if loaded is None:
+            return state
+        if state is None:
+            return loaded
+        with observe.span("state_merge", cat="state", analyzer=self.name):
+            return loaded.merge(state)
+
+    def save_state(self, persister: "StatePersister", state: State) -> None:
+        """`state_save`: the persister's serialization and write."""
+        with observe.span("state_save", cat="state", analyzer=self.name):
+            persister.persist(self, state)
 
     def aggregate_state_to(
         self,

@@ -41,6 +41,11 @@ class StateLoader:
 
 
 class StatePersister:
+    # True where `persist` may run on several threads at once: the
+    # runner's scan then writes its states concurrently and waits for
+    # all (runners/state_io.py); False keeps each persist on the caller
+    concurrent_calls = False
+
     def persist(self, analyzer: "Analyzer", state: State) -> None:
         raise NotImplementedError
 
@@ -164,6 +169,12 @@ class FileSystemStateProvider(StateLoader, StatePersister):
         self.allow_overwrite = allow_overwrite
         self.filesystem = resolve_filesystem(filesystem)
         self.naming = naming
+
+    @property
+    def concurrent_calls(self) -> bool:
+        # each analyzer has files of its own, so persists may overlap
+        # wherever the filesystem's calls may
+        return self.filesystem.concurrent_calls
 
     def _identifier(self, analyzer: "Analyzer") -> str:
         if self.naming == "reference":

@@ -38,6 +38,12 @@ from typing import Dict, Iterator, Optional
 class FileSystem:
     """Minimal persistence interface; paths are opaque strings."""
 
+    # True where calls may run on several threads at once: a
+    # `FileSystemStateProvider` over it then persists a run's states
+    # concurrently (runners/state_io.py); False keeps every call on the
+    # caller's thread
+    concurrent_calls = False
+
     def exists(self, path: str) -> bool:
         raise NotImplementedError
 
@@ -68,6 +74,8 @@ class LocalFileSystem(FileSystem):
     """POSIX-backed default. Atomicity = write to a sibling tmp name,
     fsync-free rename (the same guarantee the reference's tmp+rename
     gives); parent directories are created on demand."""
+
+    concurrent_calls = True
 
     def exists(self, path: str) -> bool:
         return os.path.exists(path)
@@ -121,6 +129,8 @@ class MemoryFileSystem(FileSystem):
     GCS bucket, which is why the persistence suite passing against it
     demonstrates object-store readiness."""
 
+    concurrent_calls = True
+
     def __init__(self) -> None:
         self._objects: Dict[str, bytes] = {}
         self._lock = threading.Lock()
@@ -173,6 +183,9 @@ class FsspecFileSystem(FileSystem):
     worse, not better)."""
 
     _POSIX_PROTOCOLS = frozenset({"file", "local"})
+
+    # fsspec filesystems are shared across threads by design
+    concurrent_calls = True
 
     def __init__(self, fs, rename_atomic: "bool | None" = None):
         self._fs = fs

@@ -49,6 +49,24 @@ def collect(sink) -> Iterator:
         stack.pop()
 
 
+def replay(tally) -> None:
+    """Add what the sink `tally` counted on a worker thread to this
+    thread's sinks (ints add, lists extend, dicts add per key): how a
+    dispatcher credits the work it takes back from a pool to the
+    `monitored()` blocks around it. The tracer is not fed again, since
+    the worker's records reached it directly."""
+    for sink in _sinks():
+        for name, value in vars(tally).items():
+            if isinstance(value, list):
+                getattr(sink, name).extend(value)
+            elif isinstance(value, dict):
+                mine = getattr(sink, name)
+                for key, n in value.items():
+                    mine[key] = mine.get(key, 0) + n
+            else:
+                setattr(sink, name, getattr(sink, name) + value)
+
+
 def record_pass(label: str) -> None:
     """One fused scan over a dataset (≈ one Spark job)."""
     for sink in _sinks():

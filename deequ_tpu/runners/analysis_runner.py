@@ -20,6 +20,7 @@ from deequ_tpu.core.metrics import Metric
 from deequ_tpu.data.table import Table
 from deequ_tpu.ops.fused import FusedScanPass
 from deequ_tpu.runners.context import AnalyzerContext
+from deequ_tpu.runners.state_io import calculate_metrics
 
 if TYPE_CHECKING:
     from deequ_tpu.analyzers.state_provider import StateLoader, StatePersister
@@ -340,14 +341,11 @@ class AnalysisRunner:
                     shareable, state_cache=state_cache, forensics=forensics,
                     controller=controller,
                 ).run(data)
-            for result in results:
-                analyzer = result.analyzer
-                if result.error is not None:
-                    metrics[analyzer] = analyzer.to_failure_metric(result.error)
-                else:
-                    metrics[analyzer] = analyzer.calculate_metric(
-                        result.state, aggregate_with, save_states_with
-                    )
+            # where the persister allows concurrent calls, the states
+            # persist concurrently
+            metrics.update(
+                calculate_metrics(results, aggregate_with, save_states_with)
+            )
         for analyzer in others:
             metrics[analyzer] = analyzer.calculate(
                 data, aggregate_with, save_states_with
