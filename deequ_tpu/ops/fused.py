@@ -2169,7 +2169,17 @@ class PipelinedAggFold:
                     a.merge_agg(t, b, np)
                     for a, t, b in zip(self.analyzers, self._total, batch_aggs)
                 ]
-            shifts = wire_shifts(self.sticky)
+            if self.assisted:
+                self._fold_assisted(assisted_out, host_ctx, shard_rows)
+
+    def _fold_assisted(self, assisted_out, host_ctx, shard_rows) -> None:
+        """Finish each assisted member's per-shard artifacts on the host
+        and consume them, as an `assisted_fold` span (category `merge`)."""
+        shifts = wire_shifts(self.sticky)
+        with observe.span(
+            "assisted_fold", cat="merge", shards=int(self.n_dev),
+            members=len(self.assisted),
+        ):
             for i, (analyzer, out) in enumerate(
                 zip(self.assisted, assisted_out)
             ):

@@ -145,24 +145,32 @@ class MeshPlacement:
         return {"devices": int(self.n_devices)}
 
     def place(self, batch, built):
+        """Pad, narrow and shift each device key, then one sharded put
+        per key: a `pack` span (category `dispatch`) with an `h2d` child
+        per put, as `pack_batch_inputs` on one chip."""
         # pad to a multiple of n_devices (pow2 per shard)
         per_dev = _pad_size(
             -(-batch.num_rows // self.n_devices), self.batch_size_per_device
         )
         padded = per_dev * self.n_devices
         inputs: Dict[str, Any] = {}
-        for key in self.keys:
-            arr = runtime.pad_to(built[key], padded)
-            if np.issubdtype(arr.dtype, np.integer):
-                arr = runtime.narrow_int_wire(arr, key, self.sticky)
-            elif arr.dtype != np.bool_:
-                if np.dtype(self.dtype) == np.float32 and key.startswith("num:"):
-                    # same f32 pre-centering as pack_batch_inputs
-                    shift = resolve_shift(key, arr, self.sticky, built.get)
-                    if shift != 0.0:
-                        arr = np.asarray(arr, dtype=np.float64) - shift
-                arr = arr.astype(self.dtype)
-            inputs[key] = jax.device_put(arr, self.sharding)
+        with observe.span("pack", cat="dispatch", rows=int(batch.num_rows)):
+            for key in self.keys:
+                arr = runtime.pad_to(built[key], padded)
+                if np.issubdtype(arr.dtype, np.integer):
+                    arr = runtime.narrow_int_wire(arr, key, self.sticky)
+                elif arr.dtype != np.bool_:
+                    if np.dtype(self.dtype) == np.float32 and key.startswith("num:"):
+                        # same f32 pre-centering as pack_batch_inputs
+                        shift = resolve_shift(key, arr, self.sticky, built.get)
+                        if shift != 0.0:
+                            arr = np.asarray(arr, dtype=np.float64) - shift
+                    arr = arr.astype(self.dtype)
+                with observe.span(
+                    "h2d", cat="dispatch", bytes=int(arr.nbytes),
+                    devices=int(self.n_devices),
+                ):
+                    inputs[key] = jax.device_put(arr, self.sharding)
         return inputs, None
 
     def launch(self, inputs, _aux):
