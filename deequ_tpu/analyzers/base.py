@@ -400,10 +400,10 @@ class ScanShareableAnalyzer(Analyzer):
     def host_finish_batch(
         self, out: Any, host_inputs: Dict[str, Any], shifts: Dict[str, float]
     ) -> Any:
-        """Optional single-device hook: turn a device-produced SUMMARY
-        (e.g. the pallas hist16 radix histogram) into the regular
-        per-batch output using the batch's host-resident inputs. Called
-        before unshift_batch; default: pass through."""
+        """Optional hook: turn a device-produced SUMMARY (e.g. the pallas
+        hist16 radix histogram) into the regular per-batch output using
+        the batch's (on a mesh: the shard's) host-resident inputs.
+        Called before unshift_batch; default: pass through."""
         return out
 
     def state_from_aggregates(self, agg: Any) -> Optional[State]:

@@ -16,6 +16,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from deequ_tpu import observe
 from deequ_tpu.analyzers.base import (
     InputSpec,
     Preconditions,
@@ -446,16 +447,14 @@ class _QuantileAnalyzerBase(ScanShareableAnalyzer):
         live = xp.asarray(inputs[f"valid:{self.column}"]).astype(bool) & xp.asarray(
             inputs[where_key(getattr(self, "where", None))]
         ).astype(bool)
-        if (
-            inputs.get("__single_device")
-            and x.dtype == xp.float32
-            and _hist16_available(int(x.shape[0]))
-        ):
+        if x.dtype == xp.float32 and _hist16_available(int(x.shape[0])):
             # TPU radix-select: the MXU builds the full 16-bit histogram
             # of the sortable-key space (one-hot matmuls, ~1ns/row) and
             # the HOST walks the 65536 counts, gathering only the bins
             # that own a decimation rank (host_finish_batch) — replaces
-            # the O(n log^2 n) bitonic device sort entirely.
+            # the O(n log^2 n) bitonic device sort entirely. Inside the
+            # mesh program x is one shard, so the exactness cap holds per
+            # shard and each shard's counts are walked against its rows.
             from deequ_tpu.ops import pallas_kernels
 
             bins = pallas_kernels.f32_sortable_bin16(x, live)
@@ -514,8 +513,10 @@ class _QuantileAnalyzerBase(ScanShareableAnalyzer):
             float32 key equals a sample's, sort them, and step `rank - lo`
             into each key's run."""
         if "hist16" in out:
+            observe.counters.record_quantile_finish("hist16")
             return self._finish_hist16(out, host_inputs, shifts)
         if "lo" in out:
+            observe.counters.record_quantile_finish("sorted")
             return self._finish_sorted(out, host_inputs, shifts)
         return out
 

@@ -8,7 +8,9 @@ ints, a `pass_labels` list, a `kernel_traces` dict, the placement
 counts `placed_rows` / `device_rows`, the state-I/O counts
 `states_loaded` / `states_saved` / `state_bytes_loaded` /
 `state_bytes_saved` and the predicate-build counts
-`pred_builds_dictionary` / `pred_builds_rows` / `pred_dict_entries` —
+`pred_builds_dictionary` / `pred_builds_rows` / `pred_dict_entries`
+and the quantile host finishes `quantile_finish_hist16` /
+`quantile_finish_sorted` —
 `runtime.ExecutionStats` in practice, duck-typed so this module never
 imports the ops layer.
 
@@ -162,6 +164,23 @@ def record_predicate_eval(route: str, rows: int, entries: int) -> None:
         tracer.count(f"pred_builds_{route}", 1)
         if entries:
             tracer.count("pred_dict_entries", int(entries))
+        spans.annotate(route=route)
+
+
+def record_quantile_finish(route: str) -> None:
+    """One quantile member's per-batch (on a mesh: per-shard) output
+    finished on the host against the column's own float64 values, on
+    `route` "hist16" (the device's 16-bit radix histogram walked to the
+    bins that own a rank) or "sorted" (the device-sorted sample's key
+    runs gathered and argsorted). The route also lands as `route` on the
+    innermost open span, which is the `assisted_fold` span when the
+    pipelined fold finishes the shards."""
+    name = f"quantile_finish_{route}"
+    for sink in _sinks():
+        setattr(sink, name, getattr(sink, name) + 1)
+    tracer = spans.current_tracer()
+    if tracer is not None:
+        tracer.count(name, 1)
         spans.annotate(route=route)
 
 

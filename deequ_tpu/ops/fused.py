@@ -170,11 +170,6 @@ def get_fused_fn(
                     all_rows = jnp.arange(padded, dtype=jnp.int32) < n
                     for in_key in const_keys:
                         inputs[in_key] = all_rows
-            # trace-time marker: device-assisted members may use single-
-            # device-only strategies (e.g. the pallas hist16 radix-select,
-            # whose host finisher needs this batch's host inputs — not
-            # available per-shard in the mesh pass)
-            inputs["__single_device"] = True
             out = (
                 tuple(a.device_reduce(inputs, jnp) for a in analyzers),
                 tuple(a.device_batch(inputs, jnp) for a in assisted),

@@ -817,6 +817,9 @@ class ExecutionStats:
     pred_builds_dictionary: int = 0
     pred_builds_rows: int = 0
     pred_dict_entries: int = 0
+    # quantile host finishes by route (per batch; per shard on a mesh)
+    quantile_finish_hist16: int = 0
+    quantile_finish_sorted: int = 0
 
     @property
     def jobs(self) -> int:

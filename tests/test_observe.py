@@ -376,6 +376,23 @@ class TestCounterParity:
         names = {s.name for s in ctx.run_trace.spans()}
         assert {"grouping", "group_pass", "freq_agg"} <= names
 
+    @pytest.mark.parametrize("route", ["hist16", "sorted"])
+    def test_quantile_finish_counts_by_route(self, route):
+        from deequ_tpu.observe import counters
+
+        other = "sorted" if route == "hist16" else "hist16"
+        with observe.tracing() as tracer, runtime.monitored() as stats:
+            with observe.span("assisted_fold", cat="merge", shards=4) as fold:
+                for _ in range(3):
+                    counters.record_quantile_finish(route)
+        assert getattr(stats, f"quantile_finish_{route}") == 3
+        assert getattr(stats, f"quantile_finish_{other}") == 0
+        assert tracer.counters == {f"quantile_finish_{route}": 3}
+        assert fold.attrs["route"] == route
+        assert fold.attrs[f"quantile_finish_{route}"] == 3
+        # untraced and unmonitored: a no-op
+        counters.record_quantile_finish(route)
+
 
 # -- one family_kernel dispatch per (where, cap, dtype) group -----------------
 

@@ -122,57 +122,125 @@ class TestHist16RadixSelect:
         order = np.argsort(x[live], kind="stable")
         assert (np.diff(ref_bins[live][order]) >= 0).all()
 
-    def test_quantile_path_equals_sort_path(self, monkeypatch):
+    @staticmethod
+    def _quantile_run(
+        monkeypatch, x, use_hist, dtype=jnp.float32, mesh=None, batch=None
+    ):
+        """ApproxQuantile(x, 0.5) through the device engine on one chip in
+        `batch`-row batches (or, with `mesh`, through the mesh pass in
+        2^13-row shards) on a `dtype` wire, the hist16 route on or off.
+        Fresh program caches:
+        a program traced on the other route is never reused. Returns
+        (quantile, ExecutionStats, assisted_fold spans)."""
+        import deequ_tpu.analyzers.sketch as sketch_mod
+        from deequ_tpu import observe
+        from deequ_tpu.analyzers import ApproxQuantile
+        from deequ_tpu.data.table import Table
+        from deequ_tpu.ops import fused, runtime
+        from deequ_tpu.parallel import distributed
+
+        monkeypatch.setenv("DEEQU_TPU_PLACEMENT", "device")
+        monkeypatch.setattr(fused, "_FUSED_CACHE", {})
+        monkeypatch.setattr(distributed, "_DIST_CACHE", {})
+        # KLL seeds are content-derived (sketch._batch_seed): equal
+        # samples give equal sketches with no counter pinning
+        monkeypatch.setattr(runtime, "compute_dtype", lambda: dtype)
+        monkeypatch.setattr(sketch_mod, "_hist16_available", lambda n: use_hist)
+        t = Table.from_numpy({"x": x})
+        analyzers = [ApproxQuantile("x", 0.5)]
+        with observe.tracing() as tracer, runtime.monitored() as stats:
+            if mesh is None:
+                res = fused.FusedScanPass(analyzers, batch).run(t)
+            else:
+                res = distributed.DistributedScanPass(
+                    analyzers, mesh, batch_size_per_device=1 << 13
+                ).run(t)
+        state = res[0].state_or_raise()
+        folds = [
+            s for r in tracer.roots for s in r.walk() if s.name == "assisted_fold"
+        ]
+        return res[0].analyzer.compute_metric_from(state).value.get(), stats, folds
+
+    @pytest.fixture
+    def interpreted_hist16(self, monkeypatch):
+        calls = {"hist16": 0}
+        real_hist16 = pallas_kernels.hist16
+
+        def interpreted(bins, interpret=False):
+            calls["hist16"] += 1
+            return real_hist16(bins, interpret=True)
+
+        monkeypatch.setattr(pallas_kernels, "hist16", interpreted)
+        return calls
+
+    @staticmethod
+    def _column(seed, n):
+        rng = np.random.default_rng(seed)
+        x = rng.lognormal(3, 1, n)
+        x[rng.random(n) < 0.05] = np.nan
+        return x * np.where(rng.random(n) < 0.3, -1, 1)
+
+    def test_quantile_path_equals_sort_path(self, monkeypatch, interpreted_hist16):
         """End-to-end through the f32 device engine: both paths pick the
         same decimation ranks and read the samples off the column's own
         float64 values, so the resulting quantiles match exactly, equal
         the float64 engine's and are values of the column. Engagement is
         asserted, not assumed."""
-        import deequ_tpu.analyzers.sketch as sketch_mod
-        from deequ_tpu.analyzers import ApproxQuantile
-        from deequ_tpu.data.table import Table
-        from deequ_tpu.ops import runtime
-        from deequ_tpu.ops.fused import FusedScanPass
+        x = self._column(8, 50_000)
+        run = self._quantile_run
 
-        monkeypatch.setenv("DEEQU_TPU_PLACEMENT", "device")
-
-        rng = np.random.default_rng(8)
-        n = 50_000
-        x = rng.lognormal(3, 1, n)
-        x[rng.random(n) < 0.05] = np.nan
-        x = x * np.where(rng.random(n) < 0.3, -1, 1)
-
-        calls = {"hist16": 0}
-        real_hist16 = pallas_kernels.hist16
-
-        def interpreted_hist16(bins, interpret=False):
-            calls["hist16"] += 1
-            return real_hist16(bins, interpret=True)
-
-        def run(use_hist, dtype=jnp.float32):
-            # KLL seeds are content-derived (sketch._batch_seed): equal
-            # samples give equal sketches with no counter pinning
-            monkeypatch.setattr(runtime, "compute_dtype", lambda: dtype)
-            if use_hist:
-                monkeypatch.setattr(
-                    sketch_mod, "_hist16_available", lambda n: True
-                )
-                monkeypatch.setattr(pallas_kernels, "hist16", interpreted_hist16)
-            else:
-                monkeypatch.setattr(
-                    sketch_mod, "_hist16_available", lambda n: False
-                )
-            t = Table.from_numpy({"x": x})
-            res = FusedScanPass([ApproxQuantile("x", 0.5)]).run(t)
-            state = res[0].state_or_raise()
-            return res[0].analyzer.compute_metric_from(state).value.get()
-
-        via_hist = run(True)
-        assert calls["hist16"] >= 1  # the kernel actually ran
-        via_sort = run(False)
+        via_hist, stats, folds = run(monkeypatch, x, True)
+        assert interpreted_hist16["hist16"] >= 1  # the kernel actually ran
+        assert stats.quantile_finish_hist16 >= 1
+        assert stats.quantile_finish_sorted == 0
+        assert folds and all(f.attrs["route"] == "hist16" for f in folds)
+        via_sort, stats, folds = run(monkeypatch, x, False)
+        assert stats.quantile_finish_sorted >= 1
+        assert stats.quantile_finish_hist16 == 0
+        assert folds and all(f.attrs["route"] == "sorted" for f in folds)
         assert via_hist == via_sort, (via_hist, via_sort)
-        via_sort_f64 = run(False, jnp.float64)
+        via_sort_f64, stats, _ = run(monkeypatch, x, False, jnp.float64)
+        # the float64 wire's sample is exact on the device: no host finish
+        assert stats.quantile_finish_sorted == stats.quantile_finish_hist16 == 0
         assert via_hist == via_sort_f64, (via_hist, via_sort_f64)
+        assert via_hist in set(x[~np.isnan(x)])
+
+    def test_mesh_quantile_path_equals_sort_path(
+        self, monkeypatch, interpreted_hist16
+    ):
+        """The mesh twin: on four devices every shard builds its own
+        hist16 counts inside `shard_map`, and the host walks each against
+        the shard's rows. 50,000 rows in 2^13-row shards: one full batch,
+        then a tail whose third shard is part padding and whose fourth is
+        all padding. The quantile equals the mesh sort path's, the float64
+        mesh engine's, and one chip's over 2^13-row batches: the same
+        samples, consumed in the same order."""
+        import jax
+
+        from deequ_tpu.parallel.distributed import data_mesh
+
+        mesh = data_mesh(jax.devices()[:4])
+        x = self._column(9, 50_000)
+        run = self._quantile_run
+
+        via_hist, stats, folds = run(monkeypatch, x, True, mesh=mesh)
+        assert interpreted_hist16["hist16"] >= 1  # traced into the mesh program
+        assert stats.pass_labels[0].startswith("dist-scan[4x]")
+        # every shard of both batches walked its counts, padding ones too
+        assert stats.quantile_finish_hist16 == 2 * 4
+        assert stats.quantile_finish_sorted == 0
+        assert [f.attrs["shards"] for f in folds] == [4, 4]
+        assert all(f.attrs["route"] == "hist16" for f in folds)
+        via_sort, stats, folds = run(monkeypatch, x, False, mesh=mesh)
+        assert stats.quantile_finish_sorted == 2 * 4
+        assert stats.quantile_finish_hist16 == 0
+        assert all(f.attrs["route"] == "sorted" for f in folds)
+        assert via_hist == via_sort, (via_hist, via_sort)
+        via_f64, _, _ = run(monkeypatch, x, False, jnp.float64, mesh=mesh)
+        assert via_hist == via_f64, (via_hist, via_f64)
+        one_chip, stats, _ = run(monkeypatch, x, True, batch=1 << 13)
+        assert stats.quantile_finish_hist16 == 7  # 6 full batches + tail
+        assert via_hist == one_chip, (via_hist, one_chip)
         assert via_hist in set(x[~np.isnan(x)])
 
 

@@ -13,6 +13,7 @@ imported would make the test workers collect different tests.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -27,14 +28,18 @@ STEP_ROWS = 1 << 20
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
 
     try:
-        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     except Exception as e:  # noqa: BLE001 - any failure means "cannot describe"
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
@@ -96,3 +101,31 @@ def test_fused_scan_step_compiles_for_v5e(one_chip, no_persistent_cache, monkeyp
     }
     # HLL register max, plus moments and centered sum of squares
     assert _compile(fn, shapes) >= 3
+
+
+def test_mesh_quantile_step_compiles_for_v5e(topo, no_persistent_cache, monkeypatch):
+    """The four-chip mesh step of a quantile and a mean at 2^21 rows per
+    chip: every shard runs the hist16 kernel (and the moment fold) inside
+    `shard_map`, and no device sort is left in the program."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from deequ_tpu.analyzers import ApproxQuantile, Mean
+    from deequ_tpu.parallel.distributed import DATA_AXIS, _get_distributed_fn
+
+    monkeypatch.setattr(pallas_kernels, "_USABLE", True)
+    mesh = Mesh(np.array(topo.devices[:4]), (DATA_AXIS,))
+    sharded = NamedSharding(mesh, P(DATA_AXIS))
+    quantile, mean = ApproxQuantile("x", 0.5), Mean("x")
+    keys = {s.key for a in (quantile, mean) for s in a.input_specs()}
+    shapes = {
+        key: jax.ShapeDtypeStruct(
+            (4 << 21,), jnp.float32 if key.startswith("num:") else jnp.bool_,
+            sharding=sharded,
+        )
+        for key in keys
+    }
+    fn = _get_distributed_fn([mean], mesh, DATA_AXIS, assisted=[quantile])
+    hlo = fn.lower(shapes).compile().as_text()
+    assert hlo.count("tpu_custom_call") >= 2  # hist16 and the moment fold
+    assert "hist16" in hlo
+    assert not re.search(r"\bsort\(", hlo)
