@@ -92,7 +92,7 @@ def _decode_table(arrow_table, fastpath, wire=None) -> Table:
     decoded straight to wire buffers (decode-to-wire fusion)."""
     sp = _spans.span("arrow_decode", cat="decode")
     with sp:
-        table = Table.from_arrow(arrow_table, fastpath, wire=wire)
+        table = Table.decode_arrow(arrow_table, fastpath, wire=wire)
         if sp:
             wire_rows = getattr(table, "wire_rows", None) or {}
             fused_cols = {k.split(":", 1)[1] for k in wire_rows}

@@ -15,6 +15,8 @@ from typing import Dict, Iterator, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from deequ_tpu.observe import spans as _spans
+
 
 class ColumnType(enum.Enum):
     STRING = "StringType"
@@ -735,6 +737,20 @@ class Table:
 
     @staticmethod
     def from_arrow(arrow_table, fastpath_columns=None, wire=None) -> "Table":
+        """`decode_arrow` under a `table_from_arrow` span (category
+        `ingest`; attrs `rows`, `columns`): a table a caller hands over.
+        A streamed source decodes its batches under its own `arrow_decode`
+        span instead."""
+        with _spans.span(
+            "table_from_arrow",
+            cat="ingest",
+            rows=int(arrow_table.num_rows),
+            columns=int(arrow_table.num_columns),
+        ):
+            return Table.decode_arrow(arrow_table, fastpath_columns, wire)
+
+    @staticmethod
+    def decode_arrow(arrow_table, fastpath_columns=None, wire=None) -> "Table":
         """Decode an arrow table into engine Columns.
 
         `fastpath_columns` (a set of names, normally threaded through

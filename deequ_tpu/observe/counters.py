@@ -9,8 +9,9 @@ counts `placed_rows` / `device_rows`, the state-I/O counts
 `states_loaded` / `states_saved` / `state_bytes_loaded` /
 `state_bytes_saved` and the predicate-build counts
 `pred_builds_dictionary` / `pred_builds_rows` / `pred_dict_entries`
-and the quantile host finishes `quantile_finish_hist16` /
-`quantile_finish_sorted` —
+the quantile host finishes `quantile_finish_hist16` /
+`quantile_finish_sorted` and the column profiler's `profile_passes` /
+`profile_histograms` / `profile_stragglers` / `profile_cast_columns` —
 `runtime.ExecutionStats` in practice, duck-typed so this module never
 imports the ops layer.
 
@@ -182,6 +183,28 @@ def record_quantile_finish(route: str) -> None:
     if tracer is not None:
         tracer.count(name, 1)
         spans.annotate(route=route)
+
+
+def record_profile(
+    passes: int, histograms: int, stragglers: int, cast_columns: int
+) -> None:
+    """One column profile (profiles/column_profiler.py): the scans it
+    made, the histograms it returned, the histogram columns the pass-1
+    counts could not answer (the straggler pass counted them), and the
+    inferred-numeric string columns cast for a pass-2 scan."""
+    counts = (
+        ("profile_passes", passes),
+        ("profile_histograms", histograms),
+        ("profile_stragglers", stragglers),
+        ("profile_cast_columns", cast_columns),
+    )
+    for sink in _sinks():
+        for name, n in counts:
+            setattr(sink, name, getattr(sink, name) + n)
+    tracer = spans.current_tracer()
+    if tracer is not None:
+        for name, n in counts:
+            tracer.count(name, int(n))
 
 
 def record_pruned_groups(skipped: int, total: int) -> None:

@@ -820,6 +820,11 @@ class ExecutionStats:
     # quantile host finishes by route (per batch; per shard on a mesh)
     quantile_finish_hist16: int = 0
     quantile_finish_sorted: int = 0
+    # column profiles: scans, histograms, straggler and cast columns
+    profile_passes: int = 0
+    profile_histograms: int = 0
+    profile_stragglers: int = 0
+    profile_cast_columns: int = 0
 
     @property
     def jobs(self) -> int:

@@ -19,7 +19,16 @@ string-cast columns — and, under column pruning, decodes ONLY those
 columns from a streaming source. A table with no numeric-looking string
 columns profiles in 2 scans; the reference's 3 is the ceiling either
 way (the reference itself always pays 3:
-ColumnProfiler.scala:103-153)."""
+ColumnProfiler.scala:103-153).
+
+Traced, a profile is one `profile` span (category `profile`; attrs
+`columns`, `passes`) holding a `profile_pass` span (attrs `pass`,
+`analyzers`) for each scan it makes and one `profile_finish` span for the
+work after pass 1: statistics extraction, type inference, the synthesized
+numeric metrics, the histograms and the profiles themselves; a pass-2 or
+straggler-histogram scan, where one runs, is a `profile_pass` child of
+it. `observe.counters.record_profile` counts the scans, histograms,
+straggler columns and cast columns of every profile."""
 
 from __future__ import annotations
 
@@ -28,6 +37,7 @@ from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
+from deequ_tpu import observe
 from deequ_tpu.analyzers import (
     ApproxCountDistinct,
     ApproxQuantiles,
@@ -41,6 +51,7 @@ from deequ_tpu.analyzers import (
     Sum,
 )
 from deequ_tpu.analyzers.scan import DataTypeInstances, determine_type
+from deequ_tpu.core.exceptions import MetricCalculationRuntimeException
 from deequ_tpu.core.metrics import Distribution, DistributionValue
 from deequ_tpu.data.table import Column, ColumnType, Table
 from deequ_tpu.profiles.column_profile import (
@@ -76,11 +87,24 @@ class GenericColumnStatistics:
     type_detection_histograms: Dict[str, Dict[str, int]]
     approximate_num_distincts: Dict[str, int]
     completenesses: Dict[str, float]
+    # string columns whose DataType metric failed: the metric's exception
+    type_failures: Dict[str, BaseException] = field(default_factory=dict)
 
     def type_of(self, column: str) -> str:
+        """The column's inferred type, else its schema type. A string
+        column whose DataType metric failed has neither, and the profile
+        fails here, as upstream's `typeOf` does, with the metric's own
+        exception as the cause."""
         if column in self.inferred_types:
             return self.inferred_types[column]
-        return self.known_types[column]
+        if column in self.known_types:
+            return self.known_types[column]
+        cause = self.type_failures.get(column)
+        raise MetricCalculationRuntimeException(
+            f"cannot profile column {column!r}: its data type is unknown, "
+            + (f"its DataType metric failed: {cause}" if cause is not None
+               else "no DataType metric was computed for it")
+        ) from cause
 
 
 class ColumnProfiler:
@@ -106,184 +130,217 @@ class ColumnProfiler:
         for name in relevant:
             data.column(name)  # raises NoSuchColumnException early
 
-        # ---- Pass 1 (reference: :103-126) --------------------------------
-        # Schema-numeric columns also get their full numeric statistics
-        # HERE: their pass-2 analyzer choice never depends on pass-1
-        # inference, so fusing them saves a whole scan (see module
-        # docstring). Only inferred-numeric strings still need pass 2.
-        # NOTE for repository reuse: the analyzer-per-pass assignment
-        # changed when this fusion landed, so a key saved by an older
-        # version misses the numeric metrics — reuse still works
-        # analyzer-by-analyzer unless fail_if_results_missing demands
-        # completeness.
-        may_need_pass2 = any(
-            data.column(name).ctype == ColumnType.STRING for name in relevant
-        )
-
-        def _with_repository(builder):
-            if metrics_repository is not None:
-                builder = builder.use_repository(metrics_repository)
-                if reuse_existing_results_for_key is not None:
-                    builder = builder.reuse_existing_results_for_key(
-                        reuse_existing_results_for_key, fail_if_results_missing
-                    )
-                if save_in_metrics_repository_using_key is not None:
-                    builder = builder.save_or_append_result(
-                        save_in_metrics_repository_using_key
-                    )
-            return builder
-
-        total_passes = 3 if may_need_pass2 else 2
-        if print_status_updates:
-            print(
-                "### PROFILING: Computing generic column statistics in "
-                f"pass (1/{total_passes})..."
+        with observe.span("profile", cat="profile", columns=len(relevant)) as prof:
+            # ---- Pass 1 (reference: :103-126) --------------------------------
+            # Schema-numeric columns also get their full numeric statistics
+            # HERE: their pass-2 analyzer choice never depends on pass-1
+            # inference, so fusing them saves a whole scan (see module
+            # docstring). Only inferred-numeric strings still need pass 2.
+            # NOTE for repository reuse: the analyzer-per-pass assignment
+            # changed when this fusion landed, so a key saved by an older
+            # version misses the numeric metrics — reuse still works
+            # analyzer-by-analyzer unless fail_if_results_missing demands
+            # completeness.
+            may_need_pass2 = any(
+                data.column(name).ctype == ColumnType.STRING for name in relevant
             )
-        from deequ_tpu.profiles.internal_analyzers import (
-            LowCardCountsState,
-            OptimisticNumericState,
-            _LowCardCounts,
-            _OptimisticNumericStats,
-            synthesize_numeric_metrics,
-        )
 
-        # optimistic members fold passes 2 and 3 into pass 1 (see
-        # internal_analyzers module docstring); the count cap leaves HLL
-        # estimation error (rsd 0.05) generous headroom over the
-        # histogram threshold
-        lcc_cap = max(4 * low_cardinality_histogram_threshold, 256)
-        analyzers_pass1 = [Size()]
-        for name in relevant:
-            analyzers_pass1.append(Completeness(name))
-            analyzers_pass1.append(ApproxCountDistinct(name))
-            ctype = data.column(name).ctype
-            if ctype == ColumnType.STRING:
-                analyzers_pass1.append(DataType(name))
-                analyzers_pass1.append(_LowCardCounts(name, lcc_cap))
-                analyzers_pass1.append(_OptimisticNumericStats(name))
-            elif ctype == ColumnType.BOOLEAN:
-                analyzers_pass1.append(_LowCardCounts(name, lcc_cap))
-            elif ctype.is_numeric:
-                analyzers_pass1.extend(_numeric_stat_analyzers(name))
+            def _with_repository(builder):
+                if metrics_repository is not None:
+                    builder = builder.use_repository(metrics_repository)
+                    if reuse_existing_results_for_key is not None:
+                        builder = builder.reuse_existing_results_for_key(
+                            reuse_existing_results_for_key, fail_if_results_missing
+                        )
+                    if save_in_metrics_repository_using_key is not None:
+                        builder = builder.save_or_append_result(
+                            save_in_metrics_repository_using_key
+                        )
+                return builder
 
-        results_pass1 = _with_repository(
-            AnalysisRunner.on_data(data)
-            .add_analyzers(analyzers_pass1)
-            .with_engine(engine, mesh)
-        ).run()
-
-        generic_stats = _extract_generic_statistics(relevant, data, results_pass1)
-        low_card_counts: Dict[str, LowCardCountsState] = {}
-        optimistic_numeric: Dict[str, OptimisticNumericState] = {}
-        for analyzer, metric in results_pass1.metric_map.items():
-            if not metric.value.is_success:
-                continue
-            state = metric.value.get()
-            if isinstance(analyzer, _LowCardCounts) and isinstance(
-                state, LowCardCountsState
-            ):
-                if not state.aborted:
-                    low_card_counts[analyzer.column] = state
-            elif isinstance(analyzer, _OptimisticNumericStats) and isinstance(
-                state, OptimisticNumericState
-            ):
-                if state.usable:
-                    optimistic_numeric[analyzer.column] = state
-
-        # ---- Pass 2 (reference: :128-153, cast at :399-417) --------------
-        # runs ONLY for inferred-numeric STRING columns, which need the
-        # post-inference cast; schema-numeric stats came from pass 1
-        numeric_columns = [
-            name
-            for name in relevant
-            if generic_stats.type_of(name)
-            in (DataTypeInstances.INTEGRAL, DataTypeInstances.FRACTIONAL)
-        ]
-        cast_columns = [
-            name for name in numeric_columns if name in generic_stats.inferred_types
-        ]
-        combined = results_pass1
-        # optimistic pass-1 stats replace pass 2 for columns where they
-        # survived (every value cast cleanly — guaranteed whenever
-        # inference landed numeric, see internal_analyzers). With a reuse
-        # key the classic pass keeps its repository short-circuit
-        # semantics instead.
-        synthesized: Dict = {}
-        if reuse_existing_results_for_key is None:
-            for name in list(cast_columns):
-                state = optimistic_numeric.get(name)
-                if state is not None:
-                    synthesized.update(
-                        synthesize_numeric_metrics(name, state, _PERCENTILES)
-                    )
-                    cast_columns.remove(name)
-        if synthesized:
-            from deequ_tpu.runners.context import AnalyzerContext
-
-            synthesized_ctx = AnalyzerContext(synthesized)
-            combined = combined + synthesized_ctx
-            if (
-                metrics_repository is not None
-                and save_in_metrics_repository_using_key is not None
-            ):
-                AnalysisRunner._save_or_append(
-                    metrics_repository,
-                    save_in_metrics_repository_using_key,
-                    synthesized_ctx,
-                )
-        analyzers_pass2 = []
-        for name in cast_columns:
-            analyzers_pass2.extend(_numeric_stat_analyzers(name))
-        if analyzers_pass2:
+            total_passes = 3 if may_need_pass2 else 2
             if print_status_updates:
                 print(
-                    "### PROFILING: Computing numeric column statistics "
-                    f"in pass (2/{total_passes})..."
+                    "### PROFILING: Computing generic column statistics in "
+                    f"pass (1/{total_passes})..."
                 )
-            casted_data = _cast_numeric_string_columns(
-                cast_columns, data, generic_stats
-            )
-            # same repository options as every other pass
-            # (reference: ColumnProfiler.scala:128-153 threads them through)
-            combined = combined + _with_repository(
-                AnalysisRunner.on_data(casted_data)
-                .add_analyzers(analyzers_pass2)
-                .with_engine(engine, mesh)
-            ).run()
-        numeric_stats = _extract_numeric_statistics(combined)
-
-        # ---- Pass 3 (reference: :487-565) --------------------------------
-        # Normally already answered by the pass-1 _LowCardCounts fold; a
-        # separate counting pass runs only for stragglers (column whose
-        # exact distinct blew the optimistic cap while its HLL estimate
-        # still cleared the threshold — possible but rare at rsd 0.05).
-        target_columns = _find_target_columns_for_histograms(
-            data, generic_stats, low_cardinality_histogram_threshold
-        )
-        histograms: Dict[str, Distribution] = {}
-        stragglers = []
-        for name in target_columns:
-            state = low_card_counts.get(name)
-            if state is None:
-                stragglers.append(name)
-                continue
-            histograms[name] = _distribution_from_counts(
-                data.column(name).ctype,
-                state.as_dict(),
-                state.null_count,
-                generic_stats.num_records,
-            )
-        if stragglers:
-            if print_status_updates:
-                print(
-                    "### PROFILING: Computing histograms of low-cardinality "
-                    f"columns in pass ({total_passes}/{total_passes})..."
-                )
-            histograms.update(
-                _compute_histograms(data, stragglers, generic_stats.num_records)
+            from deequ_tpu.profiles.internal_analyzers import (
+                LowCardCountsState,
+                OptimisticNumericState,
+                _LowCardCounts,
+                _OptimisticNumericStats,
+                synthesize_numeric_metrics,
             )
 
-        return _create_profiles(relevant, generic_stats, numeric_stats, histograms)
+            # optimistic members fold passes 2 and 3 into pass 1 (see
+            # internal_analyzers module docstring); the count cap leaves HLL
+            # estimation error (rsd 0.05) generous headroom over the
+            # histogram threshold
+            lcc_cap = max(4 * low_cardinality_histogram_threshold, 256)
+            analyzers_pass1 = [Size()]
+            for name in relevant:
+                analyzers_pass1.append(Completeness(name))
+                analyzers_pass1.append(ApproxCountDistinct(name))
+                ctype = data.column(name).ctype
+                if ctype == ColumnType.STRING:
+                    analyzers_pass1.append(DataType(name))
+                    analyzers_pass1.append(_LowCardCounts(name, lcc_cap))
+                    analyzers_pass1.append(_OptimisticNumericStats(name))
+                elif ctype == ColumnType.BOOLEAN:
+                    analyzers_pass1.append(_LowCardCounts(name, lcc_cap))
+                elif ctype.is_numeric:
+                    analyzers_pass1.extend(_numeric_stat_analyzers(name))
+
+            with observe.span(
+                "profile_pass",
+                cat="profile",
+                **{"pass": 1, "analyzers": len(analyzers_pass1)},
+            ):
+                results_pass1 = _with_repository(
+                    AnalysisRunner.on_data(data)
+                    .add_analyzers(analyzers_pass1)
+                    .with_engine(engine, mesh)
+                ).run()
+            passes = 1
+
+            with observe.span("profile_finish", cat="profile"):
+                generic_stats = _extract_generic_statistics(
+                    relevant, data, results_pass1
+                )
+                low_card_counts: Dict[str, LowCardCountsState] = {}
+                optimistic_numeric: Dict[str, OptimisticNumericState] = {}
+                for analyzer, metric in results_pass1.metric_map.items():
+                    if not metric.value.is_success:
+                        continue
+                    state = metric.value.get()
+                    if isinstance(analyzer, _LowCardCounts) and isinstance(
+                        state, LowCardCountsState
+                    ):
+                        if not state.aborted:
+                            low_card_counts[analyzer.column] = state
+                    elif isinstance(analyzer, _OptimisticNumericStats) and isinstance(
+                        state, OptimisticNumericState
+                    ):
+                        if state.usable:
+                            optimistic_numeric[analyzer.column] = state
+
+                # ---- Pass 2 (reference: :128-153, cast at :399-417) --------------
+                # runs ONLY for inferred-numeric STRING columns, which need the
+                # post-inference cast; schema-numeric stats came from pass 1
+                numeric_columns = [
+                    name
+                    for name in relevant
+                    if generic_stats.type_of(name)
+                    in (DataTypeInstances.INTEGRAL, DataTypeInstances.FRACTIONAL)
+                ]
+                cast_columns = [
+                    name
+                    for name in numeric_columns
+                    if name in generic_stats.inferred_types
+                ]
+                combined = results_pass1
+                # optimistic pass-1 stats replace pass 2 for columns where they
+                # survived (every value cast cleanly — guaranteed whenever
+                # inference landed numeric, see internal_analyzers). With a reuse
+                # key the classic pass keeps its repository short-circuit
+                # semantics instead.
+                synthesized: Dict = {}
+                if reuse_existing_results_for_key is None:
+                    for name in list(cast_columns):
+                        state = optimistic_numeric.get(name)
+                        if state is not None:
+                            synthesized.update(
+                                synthesize_numeric_metrics(name, state, _PERCENTILES)
+                            )
+                            cast_columns.remove(name)
+                if synthesized:
+                    from deequ_tpu.runners.context import AnalyzerContext
+
+                    synthesized_ctx = AnalyzerContext(synthesized)
+                    combined = combined + synthesized_ctx
+                    if (
+                        metrics_repository is not None
+                        and save_in_metrics_repository_using_key is not None
+                    ):
+                        AnalysisRunner._save_or_append(
+                            metrics_repository,
+                            save_in_metrics_repository_using_key,
+                            synthesized_ctx,
+                        )
+                analyzers_pass2 = []
+                for name in cast_columns:
+                    analyzers_pass2.extend(_numeric_stat_analyzers(name))
+                if analyzers_pass2:
+                    if print_status_updates:
+                        print(
+                            "### PROFILING: Computing numeric column statistics "
+                            f"in pass (2/{total_passes})..."
+                        )
+                    with observe.span(
+                        "profile_pass",
+                        cat="profile",
+                        **{"pass": 2, "analyzers": len(analyzers_pass2)},
+                    ):
+                        casted_data = _cast_numeric_string_columns(
+                            cast_columns, data, generic_stats
+                        )
+                        # same repository options as every other pass
+                        # (reference: ColumnProfiler.scala:128-153 threads them through)
+                        combined = combined + _with_repository(
+                            AnalysisRunner.on_data(casted_data)
+                            .add_analyzers(analyzers_pass2)
+                            .with_engine(engine, mesh)
+                        ).run()
+                    passes += 1
+                numeric_stats = _extract_numeric_statistics(combined)
+
+                # ---- Pass 3 (reference: :487-565) --------------------------------
+                # Normally already answered by the pass-1 _LowCardCounts fold; a
+                # separate counting pass runs only for stragglers (column whose
+                # exact distinct blew the optimistic cap while its HLL estimate
+                # still cleared the threshold — possible but rare at rsd 0.05).
+                target_columns = _find_target_columns_for_histograms(
+                    data, generic_stats, low_cardinality_histogram_threshold
+                )
+                histograms: Dict[str, Distribution] = {}
+                stragglers = []
+                for name in target_columns:
+                    state = low_card_counts.get(name)
+                    if state is None:
+                        stragglers.append(name)
+                        continue
+                    histograms[name] = _distribution_from_counts(
+                        data.column(name).ctype,
+                        state.as_dict(),
+                        state.null_count,
+                        generic_stats.num_records,
+                    )
+                if stragglers:
+                    if print_status_updates:
+                        print(
+                            "### PROFILING: Computing histograms of low-cardinality "
+                            f"columns in pass ({total_passes}/{total_passes})..."
+                        )
+                    with observe.span(
+                        "profile_pass",
+                        cat="profile",
+                        **{"pass": 3, "analyzers": len(stragglers)},
+                    ):
+                        histograms.update(
+                            _compute_histograms(
+                                data, stragglers, generic_stats.num_records
+                            )
+                        )
+                    passes += 1
+
+                profiles = _create_profiles(
+                    relevant, generic_stats, numeric_stats, histograms
+                )
+            prof.set(passes=passes)
+            observe.counters.record_profile(
+                passes, len(histograms), len(stragglers), len(cast_columns)
+            )
+        return profiles
 
 
 def _extract_generic_statistics(
@@ -295,11 +352,15 @@ def _extract_generic_statistics(
     type_detection: Dict[str, Dict[str, int]] = {}
     approx_distincts: Dict[str, int] = {}
     completenesses: Dict[str, float] = {}
+    type_failures: Dict[str, BaseException] = {}
 
     for analyzer, metric in results.metric_map.items():
         if isinstance(analyzer, Size) and metric.value.is_success:
             num_records = int(metric.value.get())
-        elif isinstance(analyzer, DataType) and metric.value.is_success:
+        elif isinstance(analyzer, DataType):
+            if not metric.value.is_success:
+                type_failures[analyzer.column] = metric.value.exception
+                continue
             dist = metric.value.get()
             inferred_types[analyzer.column] = determine_type(dist)
             type_detection[analyzer.column] = {
@@ -329,6 +390,7 @@ def _extract_generic_statistics(
         type_detection,
         approx_distincts,
         completenesses,
+        type_failures,
     )
 
 

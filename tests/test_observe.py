@@ -393,6 +393,21 @@ class TestCounterParity:
         # untraced and unmonitored: a no-op
         counters.record_quantile_finish(route)
 
+    def test_profile_counts(self):
+        from deequ_tpu.observe import counters
+
+        with observe.tracing() as tracer, runtime.monitored() as stats:
+            with observe.span("profile", cat="profile") as prof:
+                counters.record_profile(3, 4, 1, 2)
+                counters.record_profile(1, 2, 0, 0)
+        want = {"profile_passes": 4, "profile_histograms": 6,
+                "profile_stragglers": 1, "profile_cast_columns": 2}
+        assert {k: getattr(stats, k) for k in want} == want
+        assert tracer.counters == want
+        assert {k: prof.attrs[k] for k in want} == want
+        # untraced and unmonitored: a no-op
+        counters.record_profile(1, 1, 1, 1)
+
 
 # -- one family_kernel dispatch per (where, cap, dtype) group -----------------
 

@@ -266,7 +266,7 @@ class TestMaskedMomentFolds:
     def _blocked_reference(x, m):
         """The kernel's exact accumulation order in plain jnp ops:
         (8, 128) Kahan-compensated lane accumulators over the sequential
-        grid, then the same tiny lane-reduce epilog."""
+        grid, then the same compensated lane-reduce epilog."""
         x3 = x.reshape(-1, 8, 128)
         m3 = m.reshape(-1, 8, 128)
         cnt = jnp.zeros((8, 128), jnp.float32)
@@ -279,7 +279,7 @@ class TestMaskedMomentFolds:
             t = tot + y
             comp = (t - tot) - y
             tot = t
-        return jnp.sum(cnt), jnp.sum(tot - comp)
+        return jnp.sum(cnt), pallas_kernels._lane_total(tot, comp)
 
     @pytest.mark.parametrize("n", [1024, 4096, 1 << 14])
     def test_bitwise_vs_blocked_xla_reference(self, n):
@@ -322,6 +322,49 @@ class TestMaskedMomentFolds:
         )
         want = ((key - np.float32(avg)) ** 2).sum()
         assert abs(float(m2) - want) / want < 1e-6
+
+    def test_lane_total_is_the_exact_sum_rounded_once(self):
+        """The epilog's TwoSum tree: lanes whose plain f32 sum loses the
+        small terms (in any order) still total the exact sum, rounded
+        once, and the Kahan compensations count."""
+        rng = np.random.default_rng(31)
+        total = rng.normal(size=(8, 128)).astype(np.float32) * np.float32(1e7)
+        total[0, :64] = 1e16  # large lanes that cancel ...
+        total[1, :64] = -1e16
+        comp = (rng.normal(size=(8, 128)) * 1e-2).astype(np.float32)
+        exact = total.astype(np.float64).sum() - comp.astype(np.float64).sum()
+        got = float(pallas_kernels._lane_total(jnp.asarray(total), jnp.asarray(comp)))
+        assert got == float(np.float32(exact))
+
+    def test_sorted_key_stddev_over_two_batches_holds_3e_7(self):
+        """TPC-H l_orderkey as a table lays it out (sorted, so the two
+        batches' means lie far apart) at SF1's batch sizes: the per-batch
+        count, sum and m2 from the kernels, merged as the analyzers'
+        Chan merge does on the host, give the population standard
+        deviation well within the moments' 3e-7 (a plain f32 lane reduce
+        read 3.03e-7 on the chip and 4.4e-7 here for this seed)."""
+        from benchmark.data import tpch
+
+        key = tpch.lineitem(6_001_215, 3_131_000_014)["l_orderkey"].astype(np.float64)
+        parts = []
+        for lo, hi, size in ((0, 1 << 22, 1 << 22), (1 << 22, len(key), 1 << 21)):
+            xb = np.zeros(size, np.float32)
+            xb[: hi - lo] = key[lo:hi]
+            mb = np.zeros(size, np.float32)
+            mb[: hi - lo] = 1.0
+            cnt, total = pallas_kernels.masked_moments(
+                jnp.asarray(xb), jnp.asarray(mb), interpret=True)
+            avg = total / cnt
+            m2 = pallas_kernels.masked_centered_sumsq(
+                jnp.asarray(xb), jnp.asarray(mb), avg, interpret=True)
+            # the device's float32 results, merged in float64 on the host
+            parts.append((np.float64(cnt), np.float64(avg), np.float64(m2)))
+        (na, aa, ma), (nb, ab, mb_) = parts
+        n = na + nb
+        delta = ab - aa
+        m2 = ma + mb_ + delta * delta * na * nb / n
+        std = float(np.sqrt(m2 / n))
+        assert abs(std - key.std()) / key.std() < 3e-7 / 2
 
     def test_all_masked_yields_identities(self):
         x, m = self._data(1024, seed=5, all_masked=True)

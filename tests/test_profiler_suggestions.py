@@ -150,6 +150,31 @@ class TestColumnProfiler:
         )
         assert set(profiles.profiles) == {"id", "status"}
 
+    def test_failed_data_type_names_the_column_and_its_cause(self, monkeypatch):
+        """A string column whose DataType metric failed has no type: the
+        profile fails, as upstream's `typeOf` does, naming the column and
+        chaining the metric's own exception (not a bare KeyError)."""
+        from deequ_tpu.analyzers import DataType
+        from deequ_tpu.core.exceptions import MetricCalculationRuntimeException
+
+        planted = RuntimeError("planted DataType failure")
+
+        def fail(self, state):
+            if self.column == "status":
+                return self.to_failure_metric(planted)
+            return real(self, state)
+
+        real = DataType.compute_metric_from
+        monkeypatch.setattr(DataType, "compute_metric_from", fail)
+        with pytest.raises(MetricCalculationRuntimeException) as err:
+            ColumnProfilerRunner.on_data(example_table()).run()
+        assert not isinstance(err.value, KeyError)
+        message = str(err.value)
+        assert "'status'" in message and "planted DataType failure" in message
+        cause = err.value.__cause__
+        assert isinstance(cause, MetricCalculationRuntimeException)
+        assert cause.__cause__ is planted
+
     def test_cardinality_threshold(self):
         data = example_table()
         profiles = (
